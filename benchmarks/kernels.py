@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import timeit_call
-from repro.launch.mesh import HBM_BW
+from repro.launch.mesh import V5E, chip_peaks
 
 BENCH_JSON = pathlib.Path(__file__).resolve().parent.parent / \
     "BENCH_kernels.json"
@@ -52,6 +52,8 @@ STEP_LAT_S = 1e-6
 # split combine: one (S, G, hd) f32 renormalisation on already-resident
 # partials — a handful of VPU ops + max/sum reduces
 COMBINE_LAT_S = 2e-6
+# the latency model is of a TPU v5e, whatever host runs this script
+HBM_BW = chip_peaks(V5E)["hbm_bw"]
 
 F32 = 4
 

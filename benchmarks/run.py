@@ -23,6 +23,8 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (accuracy, eviction_overhead, kernels, latency,
                             obs_overhead, page_size_ablation, paper_claims,
                             roofline, throughput)

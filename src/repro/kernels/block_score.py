@@ -25,7 +25,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
+from repro.kernels.paged_attention import unit_rows
 
 _EPS = 1e-6
 
@@ -41,25 +43,28 @@ def _block_score_kernel(k_ref, v_ref, pos_ref, o_ref):
     valid = pos >= 0
     cnt = jnp.sum(valid.astype(jnp.float32))
     ssum = jnp.sum(jnp.where(valid, tok, 0.0))
-    o_ref[0, 0] = jnp.where(cnt > 0, ssum / jnp.maximum(cnt, 1.0),
-                            jnp.float32(jnp.inf))
+    o_ref[...] = jnp.full((1, 1), jnp.where(cnt > 0,
+                                            ssum / jnp.maximum(cnt, 1.0),
+                                            jnp.float32(jnp.inf)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def block_score_kernel(k_pool, v_pool, pos, *, interpret: bool = True):
+def block_score_kernel(k_pool, v_pool, pos, *, interpret: bool | None = None):
     """k_pool, v_pool: (N, page, KV, hd); pos: (N, page) int32
     -> per-physical-page scores (N,) f32."""
     N, page, KV, hd = k_pool.shape
+    # per-page rows and scores carry a unit axis so each block spans its
+    # array's two trailing dims (Mosaic's block tiling rule)
     out = pl.pallas_call(
         _block_score_kernel,
         grid=(N,),
         in_specs=[
             pl.BlockSpec((None, page, KV, hd), lambda n: (n, 0, 0, 0)),
             pl.BlockSpec((None, page, KV, hd), lambda n: (n, 0, 0, 0)),
-            pl.BlockSpec((1, page), lambda n: (n, 0)),
+            pl.BlockSpec((None, 1, page), lambda n: (n, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda n: (n, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
-        interpret=interpret,
-    )(k_pool, v_pool, pos)
-    return out[:, 0]
+        out_specs=pl.BlockSpec((None, 1, 1), lambda n: (n, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 1, 1), jnp.float32),
+        interpret=interpret_mode(interpret),
+    )(k_pool, v_pool, unit_rows(pos))
+    return out[:, 0, 0]

@@ -54,7 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.paged_attention import _pool_index
+from repro.kernels import interpret_mode
+from repro.kernels.paged_attention import _pool_index, unit_rows
 
 NEG_INF = -1e30
 
@@ -114,7 +115,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     "window", "scale", "block_q", "block_k", "interpret"))
 def flash_attention_kernel(q, k, v, *, window: int = 0, scale: float | None = None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """Causal GQA flash attention.
 
     q: (B, S, H, hd); k, v: (B, S, KV, hd) -> (B, S, H, hd).
@@ -126,7 +127,9 @@ def flash_attention_kernel(q, k, v, *, window: int = 0, scale: float | None = No
     scale = scale if scale is not None else hd ** -0.5
     bq = min(block_q, S)
     bk = min(block_k, S)
-    assert S % bq == 0 and S % bk == 0, (S, bq, bk)
+    if S % bq or S % bk:
+        raise ValueError(f"sequence {S} is not a multiple of the blocks "
+                         f"({bq}, {bk}); pad upstream")
     nq, nk = S // bq, S // bk
 
     # layout: heads major so blocks are (block, hd) tiles
@@ -154,7 +157,7 @@ def flash_attention_kernel(q, k, v, *, window: int = 0, scale: float | None = No
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qT, kT, vT)
     return jnp.swapaxes(out, 1, 2)
 
@@ -162,6 +165,13 @@ def flash_attention_kernel(q, k, v, *, window: int = 0, scale: float | None = No
 # ---------------------------------------------------------------------------
 # paged chunked prefill (block-table indirection, scalar prefetch)
 # ---------------------------------------------------------------------------
+
+def _row_spec(n, row_of):
+    """BlockSpec of one (1, n) row of a :func:`unit_rows` operand, row
+    chosen by ``row_of(b, h, p, bt)``."""
+    return pl.BlockSpec((None, 1, n),
+                        lambda b, h, p, bt: (row_of(b, h, p, bt), 0, 0))
+
 
 def _paged_prefill_kernel(bt_ref, q_ref, k_ref, v_ref, qpos_ref, kpos_ref,
                           *refs, num_pages: int, window: int, scale: float,
@@ -239,7 +249,7 @@ def _paged_prefill_kernel(bt_ref, q_ref, k_ref, v_ref, qpos_ref, kpos_ref,
     "window", "scale", "interpret", "return_scores"))
 def paged_flash_prefill_kernel(q, k_pool, v_pool, pos, block_table, q_pos, *,
                                window: int = 0, scale: float | None = None,
-                               interpret: bool = True,
+                               interpret: bool | None = None,
                                return_scores: bool = False):
     """Chunked-prefill attention over the shared page pool, G-fold fetch.
 
@@ -279,11 +289,13 @@ def paged_flash_prefill_kernel(q, k_pool, v_pool, pos, block_table, q_pos, *,
                               lambda b, h, p, bt: (b, h, 0, 0))]
     out_shapes = [jax.ShapeDtypeStruct((B, KV, G * T, hd), q.dtype)]
     if return_scores:
-        norm = lambda b, h, p, bt: (b, h, p, 0)
-        out_specs += [pl.BlockSpec((None, None, 1, page), norm),
-                      pl.BlockSpec((None, None, 1, page), norm)]
-        out_shapes += [jax.ShapeDtypeStruct((B, KV, P, page), jnp.float32),
-                       jax.ShapeDtypeStruct((B, KV, P, page), jnp.float32)]
+        # unit axis: each (1, page) norm block spans the trailing dims
+        norm = lambda b, h, p, bt: (b, h, p, 0, 0)
+        out_specs += [pl.BlockSpec((None, None, None, 1, page), norm),
+                      pl.BlockSpec((None, None, None, 1, page), norm)]
+        out_shapes += [
+            jax.ShapeDtypeStruct((B, KV, P, 1, page), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, P, 1, page), jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -293,9 +305,8 @@ def paged_flash_prefill_kernel(q, k_pool, v_pool, pos, block_table, q_pos, *,
                          lambda b, h, p, bt: (b, h, 0, 0)),
             pl.BlockSpec((None, None, page, hd), kv_map),
             pl.BlockSpec((None, None, page, hd), kv_map),
-            pl.BlockSpec((1, G * T), lambda b, h, p, bt: (b, 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, h, p, bt: (_pool_index(bt, b, p), 0)),
+            _row_spec(G * T, lambda b, h, p, bt: b),
+            _row_spec(page, lambda b, h, p, bt: _pool_index(bt, b, p)),
         ],
         out_specs=tuple(out_specs),
         scratch_shapes=[
@@ -308,13 +319,13 @@ def paged_flash_prefill_kernel(q, k_pool, v_pool, pos, block_table, q_pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=tuple(out_shapes),
-        interpret=interpret,
-    )(block_table, qf, k_pool, v_pool, qpos_f, pos)
+        interpret=interpret_mode(interpret),
+    )(block_table, qf, k_pool, v_pool, unit_rows(qpos_f), unit_rows(pos))
     out = res[0]
     # unfold: (B, KV, G*T, hd) -> (B, KV, G, T, hd) -> (B, T, H, hd)
     out = jnp.swapaxes(out.reshape(B, KV * G, T, hd), 1, 2)
     if return_scores:
-        return out, (res[1], res[2])
+        return out, (res[1][:, :, :, 0], res[2][:, :, :, 0])
     return out
 
 
@@ -322,7 +333,7 @@ def paged_flash_prefill_kernel(q, k_pool, v_pool, pos, block_table, q_pos, *,
 def paged_flash_prefill_kernel_per_qhead(q, k_pool, v_pool, pos, block_table,
                                          q_pos, *, window: int = 0,
                                          scale: float | None = None,
-                                         interpret: bool = True):
+                                         interpret: bool | None = None):
     """The retired per-Q-head instantiation — grid (B, H, P), each physical
     page DMA'd once per Q HEAD (G× the G-fold kernel's traffic on GQA).
     Kept as the bit-parity oracle for the fold (same kernel body, per-row
@@ -347,9 +358,8 @@ def paged_flash_prefill_kernel_per_qhead(q, k_pool, v_pool, pos, block_table,
             pl.BlockSpec((None, None, T, hd), lambda b, h, p, bt: (b, h, 0, 0)),
             pl.BlockSpec((None, None, page, hd), kv_map),
             pl.BlockSpec((None, None, page, hd), kv_map),
-            pl.BlockSpec((1, T), lambda b, h, p, bt: (b, 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, h, p, bt: (_pool_index(bt, b, p), 0)),
+            _row_spec(T, lambda b, h, p, bt: b),
+            _row_spec(page, lambda b, h, p, bt: _pool_index(bt, b, p)),
         ],
         out_specs=pl.BlockSpec((None, None, T, hd),
                                lambda b, h, p, bt: (b, h, 0, 0)),
@@ -363,6 +373,7 @@ def paged_flash_prefill_kernel_per_qhead(q, k_pool, v_pool, pos, block_table,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
-        interpret=interpret,
-    )(block_table, jnp.swapaxes(q, 1, 2), k_pool, v_pool, q_pos, pos)
+        interpret=interpret_mode(interpret),
+    )(block_table, jnp.swapaxes(q, 1, 2), k_pool, v_pool, unit_rows(q_pos),
+      unit_rows(pos))
     return jnp.swapaxes(out, 1, 2)

@@ -1,9 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run in ``interpret=True`` mode (the body
-executes in Python via the Pallas interpreter); on a real TPU set
-``repro.kernels.ops.INTERPRET = False`` (or env REPRO_PALLAS_COMPILE=1) and
-the same ``pl.pallas_call`` lowers to Mosaic.
+Each ``pl.pallas_call`` lowers to Mosaic on a TPU backend and runs in the
+Pallas interpreter on any other (the CPU tests); the kernels decide at
+trace time (:func:`repro.kernels.interpret_mode`).
 
 The paged-attention wrappers only permute the page POOL into the kernel's
 (KV, N_pool, page, hd) tile layout — the per-request view is never
@@ -11,8 +10,6 @@ materialized; indirection happens inside the kernel through the
 scalar-prefetched block table (DESIGN.md §2).
 """
 from __future__ import annotations
-
-import os
 
 import jax.numpy as jnp
 
@@ -24,8 +21,6 @@ from repro.kernels.flash_prefill import (
     paged_flash_prefill_kernel,
 )
 from repro.kernels.paged_attention import paged_attention_kernel
-
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 
 
 def _pool_layout(arr):
@@ -68,14 +63,14 @@ def paged_attention(q, cache: PagedLayerCache, *, cur_pos, window: int = 0,
             jnp.moveaxis(cache.k_scale, 2, 0),
             jnp.moveaxis(cache.v_scale, 2, 0),
             cache.pos, cache.block_table, cur_pos,
-            window=window, scale=scale, interpret=INTERPRET,
+            window=window, scale=scale,
             num_splits=num_splits, return_scores=return_scores)
     else:
         res = paged_attention_kernel(
             q.reshape(B, KV, G, hd),
             _pool_layout(cache.k), _pool_layout(cache.v),
             cache.pos, cache.block_table, cur_pos,
-            window=window, scale=scale, interpret=INTERPRET,
+            window=window, scale=scale,
             num_splits=num_splits, return_scores=return_scores)
     if return_scores:
         out, norms = res
@@ -103,8 +98,7 @@ def paged_prefill_attention(q, cache: PagedLayerCache, *, q_pos,
     res = paged_flash_prefill_kernel(
         q, _pool_layout(k_pool), _pool_layout(v_pool),
         cache.pos, cache.block_table, q_pos,
-        window=window, scale=scale, interpret=INTERPRET,
-        return_scores=return_scores)
+        window=window, scale=scale, return_scores=return_scores)
     if return_scores:
         out, norms = res
         return out, _epilogue_scores(cache, norms, tp_axis)
@@ -123,8 +117,7 @@ def page_scores(cache: PagedLayerCache):
         k_pool, v_pool = cache.k_dequant(), cache.v_dequant()
     else:
         k_pool, v_pool = cache.k, cache.v
-    pool = block_score_kernel(k_pool, v_pool, cache.pos,
-                              interpret=INTERPRET)          # (N,)
+    pool = block_score_kernel(k_pool, v_pool, cache.pos)    # (N,)
     return jnp.where(cache.mapped_mask(),
                      jnp.take(pool, jnp.maximum(cache.block_table, 0)),
                      jnp.inf)
@@ -134,5 +127,4 @@ def flash_attention(q, k, v, *, window: int = 0, scale: float | None = None,
                     block_q: int = 128, block_k: int = 128):
     """Causal GQA flash attention. q: (B,S,H,hd); k,v: (B,S,KV,hd)."""
     return flash_attention_kernel(q, k, v, window=window, scale=scale,
-                                  block_q=block_q, block_k=block_k,
-                                  interpret=INTERPRET)
+                                  block_q=block_q, block_k=block_k)

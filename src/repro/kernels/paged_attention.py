@@ -65,7 +65,16 @@ no conflict.
 
 Layout: the wrapper (ops.py) permutes the pool to (KV, N_pool, page, hd) so
 each block is a contiguous (page, hd) tile — page_size 16 x head_dim 128 is
-MXU/VPU aligned.
+MXU/VPU aligned. Per-page rows (``pos``, int8 scales, the norm outputs)
+carry a unit axis, ``(N, 1, page)``, so each ``(1, page)`` block spans its
+array's two trailing dims, as Mosaic requires of blocks that are not
+(8, 128) multiples; ``cur_pos`` rides in SMEM as a second scalar-prefetch
+operand (DESIGN.md §2).
+
+TPU page sizes: every kernel of this package compiles for a v5e in bf16 at
+page 8, 16 and 32, with head_dim 64 and 128 (``tests/test_tpu_compile.py``
+pins page 16). Page 8 is half a bf16 sublane tile (16 rows) and compiles;
+only page 16 has run on a chip.
 """
 from __future__ import annotations
 
@@ -75,6 +84,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -98,7 +109,7 @@ def _flash_update(s, valid, v, m_scr, l_scr, acc_scr):
     acc_scr[...] = acc_new
 
 
-def _decode_step_body(bt_ref, q_ref, k, v, pos_ref, curpos_ref, refs, *,
+def _decode_step_body(bt_ref, cp_ref, q_ref, k, v, pos_ref, refs, *,
                       pages_per_split: int, num_pages: int, window: int,
                       scale: float, with_scores: bool):
     """Shared split-K body for the f32 and int8 decode kernels. ``k``/``v``
@@ -120,8 +131,8 @@ def _decode_step_body(bt_ref, q_ref, k, v, pos_ref, curpos_ref, refs, *,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[...].astype(jnp.float32)                  # (G, hd)
-    pos = pos_ref[0, :]                                 # (page,) int32
-    cur = curpos_ref[0, 0]
+    pos = pos_ref[...]                                  # (1, page) int32
+    cur = cp_ref[b]
     # this step's slot holds a live page AND is not split padding
     mapped = (p < num_pages) & (bt_ref[b, pc] >= 0)
 
@@ -130,15 +141,15 @@ def _decode_step_body(bt_ref, q_ref, k, v, pos_ref, curpos_ref, refs, *,
     valid = mapped & (pos >= 0) & (pos <= cur)
     if window > 0:
         valid &= pos > (cur - window)
-    _flash_update(s, valid[None, :], v, m_scr, l_scr, acc_scr)
+    _flash_update(s, valid, v, m_scr, l_scr, acc_scr)
 
     if with_scores:
         # byproduct epilogue: per-token K/V norms of the tile already in
         # VMEM. Padding steps (p >= P) recompute slot P-1's tile (the DMA
         # clamps the same way) and rewrite identical values — no guard
         # needed. Masking/means happen wrapper-side.
-        kn_ref[0, :] = jnp.sqrt(jnp.sum(k * k, axis=-1))
-        vn_ref[0, :] = jnp.sqrt(jnp.sum(v * v, axis=-1))
+        kn_ref[...] = _row_norms(k)
+        vn_ref[...] = _row_norms(v)
 
     @pl.when(i == pages_per_split - 1)
     def _finalize():
@@ -149,30 +160,36 @@ def _decode_step_body(bt_ref, q_ref, k, v, pos_ref, curpos_ref, refs, *,
         l_ref[...] = l_scr[...]
 
 
-def _paged_attn_kernel(bt_ref, q_ref, k_ref, v_ref, pos_ref, curpos_ref,
+def _row_norms(x):
+    """(page, hd) f32 tile -> (1, page) per-token L2 norms, lane-major to
+    match the (1, page) norm output block."""
+    return jnp.sqrt(jnp.sum(x * x, axis=-1))[None, :]
+
+
+def _paged_attn_kernel(bt_ref, cp_ref, q_ref, k_ref, v_ref, pos_ref,
                        *refs, pages_per_split: int, num_pages: int,
                        window: int, scale: float, with_scores: bool):
     """One (batch, kv_head, split, page_in_split) step.
 
     bt_ref  : (B, P) int32 block tables (scalar prefetch, SMEM)
+    cp_ref  : (B,) int32 current decode positions (scalar prefetch, SMEM)
     q_ref   : (G, hd)      this kv-head's query group
     k_ref   : (page, hd)   one PHYSICAL page of keys (block-table indexed)
     v_ref   : (page, hd)   one physical page of values
     pos_ref : (1, page)    token positions of that physical page (-1 invalid)
-    curpos_ref : (1, 1)    current decode position
     outputs : acc (G, hd), m (G, 128), l (G, 128) split partials (written on
               the split's last page step); with_scores adds kn/vn (1, page)
     scratch : m (G, 128), l (G, 128), acc (G, hd) f32
     """
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
-    _decode_step_body(bt_ref, q_ref, k, v, pos_ref, curpos_ref, refs,
+    _decode_step_body(bt_ref, cp_ref, q_ref, k, v, pos_ref, refs,
                       pages_per_split=pages_per_split, num_pages=num_pages,
                       window=window, scale=scale, with_scores=with_scores)
 
 
-def _paged_attn_kernel_int8(bt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                            pos_ref, curpos_ref, *refs, pages_per_split: int,
+def _paged_attn_kernel_int8(bt_ref, cp_ref, q_ref, k_ref, v_ref, ks_ref,
+                            vs_ref, pos_ref, *refs, pages_per_split: int,
                             num_pages: int, window: int, scale: float,
                             with_scores: bool):
     """int8 variant: K/V tiles arrive quantized; dequantization happens in
@@ -184,9 +201,16 @@ def _paged_attn_kernel_int8(bt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     ks_ref, vs_ref: (1, page) f32 absmax scales for this physical page."""
     k = k_ref[...].astype(jnp.float32) * (ks_ref[0, :] / 127.0)[:, None]
     v = v_ref[...].astype(jnp.float32) * (vs_ref[0, :] / 127.0)[:, None]
-    _decode_step_body(bt_ref, q_ref, k, v, pos_ref, curpos_ref, refs,
+    _decode_step_body(bt_ref, cp_ref, q_ref, k, v, pos_ref, refs,
                       pages_per_split=pages_per_split, num_pages=num_pages,
                       window=window, scale=scale, with_scores=with_scores)
+
+
+def unit_rows(x):
+    """(R, ..., n) -> (R, ..., 1, n): per-row operands whose (1, n) blocks
+    then span the array's two trailing dims (Mosaic's block tiling rule
+    for an n that is not a multiple of 128)."""
+    return x.reshape(*x.shape[:-1], 1, x.shape[-1])
 
 
 def _pool_index(bt_ref, b, p):
@@ -224,31 +248,87 @@ def _decode_out_shapes(B, KV, S, G, hd, P, page, with_scores):
         jax.ShapeDtypeStruct((B, KV, S, G, 128), jnp.float32),  # l
     ]
     if with_scores:
-        shapes += [jax.ShapeDtypeStruct((B, KV, P, page), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, P, page), jnp.float32)]
+        # norms carry a unit axis so each (1, page) block spans the array's
+        # two trailing dims (Mosaic's block tiling rule)
+        shapes += [jax.ShapeDtypeStruct((B, KV, P, 1, page), jnp.float32),
+                   jax.ShapeDtypeStruct((B, KV, P, 1, page), jnp.float32)]
     return tuple(shapes)
 
 
 def _decode_out_specs(G, hd, P, page, pps, with_scores):
-    part = lambda b, h, sp, i, bt: (b, h, sp, 0, 0)
+    part = lambda b, h, sp, i, bt, cp: (b, h, sp, 0, 0)
     specs = [
         pl.BlockSpec((None, None, None, G, hd), part),
         pl.BlockSpec((None, None, None, G, 128), part),
         pl.BlockSpec((None, None, None, G, 128), part),
     ]
     if with_scores:
-        norm = lambda b, h, sp, i, bt: \
-            (b, h, jnp.minimum(sp * pps + i, P - 1), 0)
-        specs += [pl.BlockSpec((None, None, 1, page), norm),
-                  pl.BlockSpec((None, None, 1, page), norm)]
+        norm = lambda b, h, sp, i, bt, cp: \
+            (b, h, jnp.minimum(sp * pps + i, P - 1), 0, 0)
+        specs += [pl.BlockSpec((None, None, None, 1, page), norm),
+                  pl.BlockSpec((None, None, None, 1, page), norm)]
     return tuple(specs)
+
+
+def _decode_call(kernel, q, k_pool, v_pool, scales, pos, block_table,
+                 cur_pos, *, num_splits: int, return_scores: bool,
+                 interpret: bool | None):
+    """Shared pallas_call of the f32 and int8 decode kernels. ``scales``:
+    () or the int8 pool's (k_scales, v_scales), each (KV, N_pool, page)."""
+    B, KV, G, hd = q.shape
+    page = pos.shape[1]
+    P = block_table.shape[1]
+    S, pps = _split_grid(P, num_splits)
+    kernel = functools.partial(kernel, pages_per_split=pps, num_pages=P,
+                               with_scores=return_scores)
+
+    def page_of(b, sp, i, bt):
+        return _pool_index(bt, b, jnp.minimum(sp * pps + i, P - 1))
+
+    def kv_map(b, h, sp, i, bt, cp):
+        return (h, page_of(b, sp, i, bt), 0, 0)
+
+    # per-page rows come in as unit_rows, one (1, page) block per page
+    meta_spec = pl.BlockSpec((None, None, 1, page), kv_map)
+    pos_spec = pl.BlockSpec(
+        (None, 1, page),
+        lambda b, h, sp, i, bt, cp: (page_of(b, sp, i, bt), 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, KV, S, pps),
+        in_specs=[
+            pl.BlockSpec((None, None, G, hd),
+                         lambda b, h, sp, i, bt, cp: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, page, hd), kv_map),
+            pl.BlockSpec((None, None, page, hd), kv_map),
+            *[meta_spec for _ in scales],
+            pos_spec,
+        ],
+        out_specs=_decode_out_specs(G, hd, P, page, pps, return_scores),
+        scratch_shapes=[
+            pltpu.VMEM((G, 128), jnp.float32),
+            pltpu.VMEM((G, 128), jnp.float32),
+            pltpu.VMEM((G, hd), jnp.float32),
+        ],
+    )
+    res = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=_decode_out_shapes(B, KV, S, G, hd, P, page, return_scores),
+        interpret=interpret_mode(interpret),
+    )(block_table, cur_pos.astype(jnp.int32), q, k_pool, v_pool,
+      *map(unit_rows, scales), unit_rows(pos))
+    out = combine_splits(res[0], res[1], res[2]).astype(q.dtype)
+    if return_scores:
+        return out, (res[3][:, :, :, 0], res[4][:, :, :, 0])
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=(
     "window", "scale", "interpret", "num_splits", "return_scores"))
 def paged_attention_kernel(q, k_pool, v_pool, pos, block_table, cur_pos, *,
                            window: int = 0, scale: float | None = None,
-                           interpret: bool = True, num_splits: int = 1,
+                           interpret: bool | None = None, num_splits: int = 1,
                            return_scores: bool = False):
     """q: (B, KV, G, hd); k_pool/v_pool: (KV, N_pool, page, hd);
     pos: (N_pool, page) int32; block_table: (B, P) int32;
@@ -257,51 +337,15 @@ def paged_attention_kernel(q, k_pool, v_pool, pos, block_table, cur_pos, *,
 
     ``num_splits``: split-K factor — the page walk runs as ceil(P/S)
     sequential steps per split instead of P, with a jnp combine across
-    splits. 1 == the classic single-chain walk (bit-compatible combine)."""
-    B, KV, G, hd = q.shape
-    page = k_pool.shape[2]
-    P = block_table.shape[1]
+    splits. 1 == the classic single-chain walk (bit-compatible combine).
+    ``interpret``: None compiles on TPU and interprets elsewhere
+    (:func:`repro.kernels.interpret_mode`)."""
+    hd = q.shape[-1]
     scale = scale if scale is not None else hd ** -0.5
-    S, pps = _split_grid(P, num_splits)
-    kernel = functools.partial(_paged_attn_kernel, pages_per_split=pps,
-                               num_pages=P, window=window, scale=scale,
-                               with_scores=return_scores)
-
-    def kv_map(b, h, sp, i, bt):
-        return (h, _pool_index(bt, b, jnp.minimum(sp * pps + i, P - 1)), 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, KV, S, pps),
-        in_specs=[
-            pl.BlockSpec((None, None, G, hd),
-                         lambda b, h, sp, i, bt: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, page, hd), kv_map),
-            pl.BlockSpec((None, None, page, hd), kv_map),
-            pl.BlockSpec((1, page),
-                         lambda b, h, sp, i, bt:
-                         (_pool_index(bt, b,
-                                      jnp.minimum(sp * pps + i, P - 1)), 0)),
-            pl.BlockSpec((1, 1), lambda b, h, sp, i, bt: (b, 0)),
-        ],
-        out_specs=_decode_out_specs(G, hd, P, page, pps, return_scores),
-        scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
-    )
-    res = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=_decode_out_shapes(B, KV, S, G, hd, P, page, return_scores),
-        interpret=interpret,
-    )(block_table, q.reshape(B, KV, G, hd), k_pool, v_pool, pos,
-      cur_pos.reshape(B, 1))
-    out = combine_splits(res[0], res[1], res[2]).astype(q.dtype)
-    if return_scores:
-        return out, (res[3], res[4])
-    return out
+    kernel = functools.partial(_paged_attn_kernel, window=window, scale=scale)
+    return _decode_call(kernel, q, k_pool, v_pool, (), pos, block_table,
+                        cur_pos, num_splits=num_splits,
+                        return_scores=return_scores, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -309,59 +353,17 @@ def paged_attention_kernel(q, k_pool, v_pool, pos, block_table, cur_pos, *,
 def paged_attention_kernel_int8(q, k_pool, v_pool, k_scales, v_scales, pos,
                                 block_table, cur_pos, *, window: int = 0,
                                 scale: float | None = None,
-                                interpret: bool = True, num_splits: int = 1,
+                                interpret: bool | None = None,
+                                num_splits: int = 1,
                                 return_scores: bool = False):
     """q: (B, KV, G, hd) f32/bf16; k_pool/v_pool: (KV, N_pool, page, hd) int8;
     k_scales/v_scales: (KV, N_pool, page) f32; pos: (N_pool, page) int32;
     block_table: (B, P) int32. Split-K + fused epilogue as the f32 kernel;
     epilogue norms are of the dequantized tiles."""
-    B, KV, G, hd = q.shape
-    page = k_pool.shape[2]
-    P = block_table.shape[1]
+    hd = q.shape[-1]
     scale = scale if scale is not None else hd ** -0.5
-    S, pps = _split_grid(P, num_splits)
-    kernel = functools.partial(_paged_attn_kernel_int8, pages_per_split=pps,
-                               num_pages=P, window=window, scale=scale,
-                               with_scores=return_scores)
-
-    def pmap(b, h, sp, i, bt):
-        return _pool_index(bt, b, jnp.minimum(sp * pps + i, P - 1))
-
-    def kv_map(b, h, sp, i, bt):
-        return (h, pmap(b, h, sp, i, bt), 0, 0)
-
-    def scale_map(b, h, sp, i, bt):
-        return (h, pmap(b, h, sp, i, bt), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, KV, S, pps),
-        in_specs=[
-            pl.BlockSpec((None, None, G, hd),
-                         lambda b, h, sp, i, bt: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, page, hd), kv_map),
-            pl.BlockSpec((None, None, page, hd), kv_map),
-            pl.BlockSpec((None, 1, page), scale_map),
-            pl.BlockSpec((None, 1, page), scale_map),
-            pl.BlockSpec((1, page),
-                         lambda b, h, sp, i, bt: (pmap(b, h, sp, i, bt), 0)),
-            pl.BlockSpec((1, 1), lambda b, h, sp, i, bt: (b, 0)),
-        ],
-        out_specs=_decode_out_specs(G, hd, P, page, pps, return_scores),
-        scratch_shapes=[
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, 128), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
-    )
-    res = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=_decode_out_shapes(B, KV, S, G, hd, P, page, return_scores),
-        interpret=interpret,
-    )(block_table, q.reshape(B, KV, G, hd), k_pool, v_pool, k_scales,
-      v_scales, pos, cur_pos.reshape(B, 1))
-    out = combine_splits(res[0], res[1], res[2]).astype(q.dtype)
-    if return_scores:
-        return out, (res[3], res[4])
-    return out
+    kernel = functools.partial(_paged_attn_kernel_int8, window=window,
+                               scale=scale)
+    return _decode_call(kernel, q, k_pool, v_pool, (k_scales, v_scales), pos,
+                        block_table, cur_pos, num_splits=num_splits,
+                        return_scores=return_scores, interpret=interpret)

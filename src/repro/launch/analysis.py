@@ -9,10 +9,11 @@ Sources:
                                collective-permute ops with ring-traffic
                                factors (see _RING_FACTORS below).
 
-Terms (seconds), per the assignment:
-  compute    = flops_per_device / PEAK_FLOPS_BF16
-  memory     = bytes_per_device / HBM_BW
-  collective = collective_bytes_per_device / ICI_BW
+Terms (seconds), against the TPU v5e peaks (``mesh.PEAKS``) — the dry-run
+compiles for placeholder devices, so the chip being modelled is named:
+  compute    = flops_per_device / flops_bf16
+  memory     = bytes_per_device / hbm_bw
+  collective = collective_bytes_per_device / ici_bw
 """
 from __future__ import annotations
 
@@ -20,7 +21,7 @@ import json
 import re
 from dataclasses import asdict, dataclass, field
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -280,9 +281,10 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str, policy: str,
     except Exception as e:  # CPU backend may not implement it
         mem["error"] = str(e)
 
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = byts / HBM_BW
-    collective_s = coll.traffic_bytes / ICI_BW
+    peak = chip_peaks(V5E)
+    compute_s = flops / peak["flops_bf16"]
+    memory_s = byts / peak["hbm_bw"]
+    collective_s = coll.traffic_bytes / peak["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)

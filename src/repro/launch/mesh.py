@@ -53,7 +53,20 @@ def make_tp_mesh(tp: int):
     return jax.make_mesh((1, tp), ("data", "model"))
 
 
-# --- hardware constants (TPU v5e; roofline denominators) --------------------
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
+# --- per-chip peaks (roofline denominators), keyed by jax ``device_kind`` ---
+# Source: Google Cloud documentation, "TPU v5e" (per chip: 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect over 4 links).
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peak FLOP/s (bf16), HBM bytes/s and ICI bytes/s per link of one chip.
+    A device missing from :data:`PEAKS` is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind {device_kind!r};"
+                       f" known: {sorted(PEAKS)}") from None

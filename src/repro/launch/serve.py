@@ -18,9 +18,10 @@ import jax
 import numpy as np
 
 from repro.configs import CacheConfig, get_arch
-from repro.models.transformer import init_model
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_tp_mesh
 from repro.obs import ObsConfig
-from repro.serving import Engine, SamplingParams
+from repro.serving import Engine, SamplingParams, init_params
 
 
 def main() -> None:
@@ -81,7 +82,9 @@ def main() -> None:
     if cfg.num_codebooks > 1:
         raise SystemExit("serve driver targets text archs; see examples/ for "
                          "audio decode")
-    params = init_model(jax.random.PRNGKey(args.seed), cfg)
+    enable_compile_cache()
+    mesh = make_tp_mesh(args.tp) if args.tp > 1 else None
+    params = init_params(cfg, jax.random.PRNGKey(args.seed), mesh)
     ccfg = CacheConfig(page_size=args.page, cache_budget=args.budget,
                        policy=args.policy,
                        dtype="float32" if args.reduced else "bfloat16")
@@ -96,7 +99,7 @@ def main() -> None:
                  sampling=SamplingParams(greedy=args.greedy),
                  chunk_size=args.chunk, token_budget=args.token_budget,
                  prefix_sharing=not args.no_prefix_sharing, obs=obs,
-                 tp=args.tp)
+                 tp=args.tp, mesh=mesh)
 
     rng = np.random.default_rng(args.seed)
     shared = rng.integers(0, cfg.vocab_size,

@@ -99,8 +99,8 @@ def attention_forward(params, cfg: ModelConfig, spec: LayerSpec, x, positions,
 
     Returns (out (B,S,D), (k, v) post-rope if return_kv else None).
     ``use_pallas``: route through the Pallas flash kernel (TPU hot path;
-    proper triangle/window block skipping) when the shape is tileable —
-    falls back to the blocked jnp path otherwise.
+    proper triangle/window block skipping); a shape the kernel cannot tile
+    raises rather than silently taking the jnp path.
     """
     q, k, v = project_qkv(params, cfg, x, positions)
     window = 0
@@ -109,8 +109,7 @@ def attention_forward(params, cfg: ModelConfig, spec: LayerSpec, x, positions,
     elif spec.attn_kind == "local":
         window = cfg.local_window
     B, S = x.shape[:2]
-    hd = cfg.resolved_head_dim
-    if use_pallas and S % 128 == 0 and hd % 8 == 0:
+    if use_pallas:
         from repro.kernels.ops import flash_attention
         out = flash_attention(q, k, v, window=window)
     else:

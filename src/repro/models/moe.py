@@ -38,17 +38,10 @@ class MoEStats(NamedTuple):
 
 
 def _shard_map(f, mesh, in_specs, out_specs, manual_axes):
-    """Version-portable shard_map: new jax exposes ``jax.shard_map`` with
-    ``axis_names`` (manual set); 0.4.x has ``jax.experimental.shard_map``
-    with the complementary ``auto`` set."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(manual_axes), check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - set(manual_axes)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
+    """``jax.shard_map`` manual over ``manual_axes`` (the rest stay auto),
+    replication unchecked."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=set(manual_axes), check_vma=False)
 
 
 def init_moe(key, cfg: ModelConfig):

@@ -57,6 +57,7 @@ from repro.models.transformer import (
     collect_step_stats,
     forward_step,
     init_decode_caches,
+    init_model,
     intact_prefix_pages,
 )
 from repro.obs import EngineObs, ObsConfig
@@ -89,11 +90,24 @@ class EngineStats:
         return self.decode_tokens / self.decode_s if self.decode_s else 0.0
 
 
+def init_params(cfg: ModelConfig, key, mesh=None):
+    """Seeded random weights for serving, made by one compiled
+    ``init_model``. Given a tensor-parallel ``mesh`` (``make_tp_mesh``),
+    every device creates only its own shards, so no device ever holds the
+    whole model — what a model larger than one chip's memory needs."""
+    init = lambda k: init_model(k, cfg)
+    if mesh is None:
+        return jax.jit(init)(key)
+    from repro.sharding import rules
+    shardings = rules.tp_param_shardings(mesh, jax.eval_shape(init, key))
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
 class Engine:
     def __init__(self, cfg: ModelConfig, params, *, cache_cfg: CacheConfig,
                  max_batch: int = 8, max_prompt_len: int = 256,
                  max_new_tokens: int = 128, sampling: SamplingParams | None = None,
-                 use_pallas: bool = False, seed: int = 0,
+                 use_pallas: bool | None = None, seed: int = 0,
                  chunk_size: int = 64, token_budget: int | None = None,
                  prefix_sharing: bool = True, decode_splits: int = 1,
                  fused_scores: bool | None = None,
@@ -118,6 +132,11 @@ class Engine:
         self.max_new_tokens = max_new_tokens
         self.total_len = max_prompt_len + max_new_tokens
         self.sampling = sampling or SamplingParams()
+        # attention kernels follow the platform: the Pallas kernels on a
+        # TPU, the jnp reference elsewhere. An explicit True off-TPU runs
+        # them in the Pallas interpreter (the CPU parity tests).
+        if use_pallas is None:
+            use_pallas = jax.default_backend() == "tpu"
         self.use_pallas = use_pallas
         # split-K decode (DESIGN.md §8): partition the page walk of the
         # Pallas decode kernel; 1 == off. Fused eviction scores default to
@@ -162,9 +181,17 @@ class Engine:
 
         # batch-wide state (block tables carry chunk headroom: a prefilling
         # row transiently holds budget + chunk tokens between boundaries)
-        self.cache: ModelCache = init_decode_caches(
-            cfg, max_batch, self.total_len, self.policy, self.ccfg,
-            chunk_tokens=self.chunk_size, track_stats=self.obs.cfg.metrics)
+        def make_cache() -> ModelCache:
+            return init_decode_caches(
+                cfg, max_batch, self.total_len, self.policy, self.ccfg,
+                chunk_tokens=self.chunk_size,
+                track_stats=self.obs.cfg.metrics)
+
+        if tp > 1:
+            self._init_tp(make_cache)
+        else:
+            self.cache: ModelCache = make_cache()
+            self._step_fn = jax.jit(self._step_impl)
         self.cur_tokens = np.zeros((max_batch,), np.int32)
 
         # running pool occupancy, maintained from the device stats deltas
@@ -181,11 +208,6 @@ class Engine:
             free += reps * (n - max_batch)
         self._pool_pages_total = total
         self._free_pages_est = free
-
-        if tp > 1:
-            self._init_tp()
-        else:
-            self._step_fn = jax.jit(self._step_impl)
         self._probe_fn = jax.jit(intact_prefix_pages)
         # lineage ledger: one jitted gather of the FIRST attention layer's
         # pool view per step (block table, ref counts, per-page tokens /
@@ -193,9 +215,10 @@ class Engine:
         self._lineage_fn = (jax.jit(self._lineage_impl)
                             if self.obs.ledger is not None else None)
 
-    def _init_tp(self) -> None:
-        """Build the tensor-parallel step: place params/cache with their
-        manual shardings and wrap ``_step_impl`` in shard_map over the
+    def _init_tp(self, make_cache) -> None:
+        """Build the tensor-parallel step: create the cache straight into
+        its manual shardings (params too, unless :func:`init_params`
+        already placed them) and wrap ``_step_impl`` in shard_map over the
         (1, tp) mesh (DESIGN.md §11). Everything host-side — the scheduler,
         radix prefix index, free-list estimate, lineage ledger — keeps
         reading the replicated metadata leaves exactly as at tp=1."""
@@ -209,20 +232,27 @@ class Engine:
             self.mesh = make_tp_mesh(self.tp)
         mesh = self.mesh
         p_specs = rules.tp_param_specs(self.params)
-        c_specs = rules.tp_cache_specs(self.cache)
         self.params = jax.device_put(
             self.params, rules.tp_param_shardings(mesh, self.params))
-        self.cache = jax.device_put(
-            self.cache, rules.tp_cache_shardings(mesh, self.cache))
+        cache_shapes = jax.eval_shape(make_cache)
+        c_specs = rules.tp_cache_specs(cache_shapes)
+        c_shardings = rules.tp_cache_shardings(mesh, cache_shapes)
+        self.cache = jax.jit(make_cache, out_shardings=c_shardings)()
         rep = P()
         in_specs = (p_specs, rep, rep, rep, rep, rep, rep, rep, c_specs, rep)
         # outputs: (next_tok replicated, cache, stats replicated-or-None,
         # taps always None under TP — gated in __init__)
         stats_spec = rep if self.obs.cfg.metrics else None
         out_specs = (rep, c_specs, stats_spec, None)
-        self._step_fn = jax.jit(_shard_map(
-            self._step_impl, mesh, in_specs=in_specs, out_specs=out_specs,
-            manual_axes=("data", "model")))
+        # pin the returned cache to the shardings it was created with: left
+        # to the compiler, replicated leaves come back with an equivalent
+        # but unequal spec, and the next step's call misses the jit cache
+        rep_sharding = NamedSharding(mesh, rep)
+        self._step_fn = jax.jit(
+            _shard_map(self._step_impl, mesh, in_specs=in_specs,
+                       out_specs=out_specs, manual_axes=("data", "model")),
+            out_shardings=(rep_sharding, c_shardings, rep_sharding,
+                           rep_sharding))
 
     @staticmethod
     def _lineage_impl(cache: ModelCache):

@@ -143,3 +143,30 @@ def test_decode_step_pallas_path_matches_ref():
                             use_pallas=True)
     np.testing.assert_allclose(np.asarray(lg_ref), np.asarray(lg_pal),
                                atol=3e-4, rtol=3e-4)
+
+
+def test_kernel_path_follows_platform(small_engine):
+    """Off a TPU the engine serves with the jnp reference by default and the
+    kernels, when asked for, run in the Pallas interpreter; a TPU backend
+    would compile them and refuse interpret mode."""
+    from repro.kernels import interpret_mode
+    eng, _ = small_engine
+    on_tpu = jax.default_backend() == "tpu"
+    assert eng.use_pallas == on_tpu
+    assert eng.fused_scores == on_tpu
+    assert interpret_mode() == (not on_tpu)
+    assert interpret_mode(False) is False
+    if not on_tpu:
+        assert interpret_mode(True) is True
+
+
+def test_init_params_matches_init_model():
+    """The compiled initializer makes the same seeded weights."""
+    from repro.serving import init_params
+    cfg = ASSIGNED_ARCHS["qwen2.5-3b"].reduced()
+    key = jax.random.PRNGKey(3)
+    got = init_params(cfg, key)
+    want = init_model(key, cfg)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6)

@@ -13,9 +13,8 @@ from repro.models.transformer import init_decode_caches, init_model
 from repro.sharding import rules
 from repro.training.optimizer import init_adamw
 
-# jax 0.4.37 constructor: a tuple of (name, size) pairs
-SINGLE = AbstractMesh((("data", 16), ("model", 16)))
-MULTI = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(tree_shapes, tree_specs, mesh):

@@ -333,6 +333,16 @@ def test_make_tp_mesh_requires_devices():
         make_tp_mesh(len(jax.devices()) + 1)
 
 
+def test_chip_peaks_table():
+    """Roofline peaks come from the table by device_kind; an unlisted
+    device is an error, not a silent v5e default."""
+    from repro.launch.mesh import V5E, chip_peaks
+    assert chip_peaks(V5E)["flops_bf16"] == 197e12
+    assert chip_peaks(V5E)["hbm_bw"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("TPU v9 imaginary")
+
+
 def test_tp_rejects_regret_taps():
     cfg = get_arch("gemma3-27b").reduced(tp=4)
     if len(jax.devices()) < 2:
