@@ -158,11 +158,10 @@ def trace_summary(events: list[dict]) -> list[dict]:
     the device pool counters carried in the trace."""
     by_kind: dict = {}
     for ev in events:
-        # schema v2 traces interleave "event" (page lineage) and "probe"
-        # (eviction regret) records with the per-step records; the timing
-        # summary only consumes steps. v1 files carry no "rec" field and
-        # are all steps.
-        if ev.get("rec", "step") != "step":
+        # traces interleave "event" (page lineage) and "probe" (eviction
+        # regret) records with the per-step records; the timing summary
+        # only consumes steps
+        if ev["rec"] != "step":
             continue
         if ev["kind"] == "idle":
             continue

@@ -69,7 +69,7 @@ def _decode_hlo(eng):
             jnp.zeros((B,), bool), jnp.zeros((B,), bool),
             jnp.full((B,), -1, jnp.int32), jnp.zeros((B,), jnp.int32),
             eng.cache, jax.random.PRNGKey(0))
-    return eng._step_fn.lower(*args).compile().as_text()
+    return eng._step_decode.lower(*args).compile().as_text()
 
 
 def run_arch(arch, *, n_reqs, new_tokens, budget=32, page=4):

@@ -120,10 +120,11 @@ def kernels_in_step(eng) -> None:
     import jax.numpy as jnp
 
     B = eng.max_batch
-    for T in (eng.chunk_size, 1):
+    for T, program in ((eng.chunk_size, eng._step_mixed),
+                       (1, eng._step_decode)):
         zeros = jnp.zeros((B,), jnp.int32)
         no = jnp.zeros((B,), bool)
-        hlo = eng._step_fn.lower(
+        hlo = program.lower(
             eng.params, jnp.zeros((B, T), jnp.int32), zeros, no, no, no,
             jnp.full((B,), -1, jnp.int32), zeros, eng.cache,
             jax.random.PRNGKey(0)).compile().as_text()

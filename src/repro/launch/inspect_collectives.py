@@ -93,13 +93,14 @@ def lower_serving_step(arch: str, tp: int, policy: str, budget: int,
     B = eng.max_batch
     key = jax.random.PRNGKey(0)
     texts = {}
-    for name, T in (("mixed", eng.chunk_size), ("decode", 1)):
+    for name, T, program in (("mixed", eng.chunk_size, eng._step_mixed),
+                             ("decode", 1, eng._step_decode)):
         args = (eng.params, jnp.zeros((B, T), jnp.int32),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool),
                 jnp.zeros((B,), bool), jnp.zeros((B,), bool),
                 jnp.full((B,), -1, jnp.int32), jnp.zeros((B,), jnp.int32),
                 eng.cache, key)
-        texts[name] = eng._step_fn.lower(*args).compile().as_text()
+        texts[name] = program.lower(*args).compile().as_text()
     eng.close()
     return texts
 

@@ -7,11 +7,18 @@
 Prints the obs metrics dashboard (latency histograms with p50/p90/p99,
 pool counters) after the run; ``--trace`` additionally writes one JSONL
 event per engine step (schema: repro.obs.trace, validate with
-``python -m repro.obs.trace FILE``)."""
+``python -m repro.obs.trace FILE``). ``--profile DIR`` runs the serve loop
+under ``jax.profiler.trace(DIR)`` with the engine's host phase spans on
+(``engine.step`` > ``engine.plan`` / ``inputs`` / ``launch`` / ``wait`` /
+``stats`` / ``emit``, DESIGN.md §9) and prints the ``.xplane.pb`` path:
+open it in XProf/TensorBoard, or read it with
+``jax.profiler.ProfileData.from_file``."""
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
+import pathlib
 import time
 
 import jax
@@ -72,8 +79,10 @@ def main() -> None:
     ap.add_argument("--no-metrics", action="store_true",
                     help="disable all engine instrumentation (the bare "
                          "baseline the BENCH_obs overhead gate compares to)")
-    ap.add_argument("--profile-annotations", action="store_true",
-                    help="wrap plan/step in jax.profiler.TraceAnnotation")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="profile the serve loop into DIR "
+                         "(jax.profiler.trace) with the engine's host phase "
+                         "spans on")
     args = ap.parse_args()
 
     cfg = get_arch(args.arch)
@@ -89,7 +98,7 @@ def main() -> None:
                        policy=args.policy,
                        dtype="float32" if args.reduced else "bfloat16")
     obs = ObsConfig(metrics=not args.no_metrics, trace_path=args.trace,
-                    profiler_annotations=args.profile_annotations,
+                    profiler_annotations=args.profile is not None,
                     timeline=args.timeline is not None,
                     lineage=args.lineage,
                     regret_every=args.regret_every)
@@ -108,9 +117,16 @@ def main() -> None:
         n = int(rng.integers(args.prompt_len // 2, args.prompt_len))
         tail = rng.integers(0, cfg.vocab_size, size=max(n - len(shared), 1))
         eng.submit(np.concatenate([shared, tail]).astype(np.int32))
+    profile = (jax.profiler.trace(args.profile) if args.profile
+               else contextlib.nullcontext())
     t0 = time.perf_counter()
-    done = eng.run()
+    with profile:
+        done = eng.run()
     dt = time.perf_counter() - t0
+    if args.profile:
+        xplane = max(pathlib.Path(args.profile).rglob("*.xplane.pb"),
+                     key=lambda f: f.stat().st_mtime)
+        print(f"wrote profile {xplane}")
     s = eng.stats
     print(f"policy={args.policy} budget={args.budget} page={args.page}")
     print(f"finished {len(done)} requests, {s.tokens_generated} tokens "

@@ -385,29 +385,37 @@ def _step_layer(lp, cfg, spec, x, cache: LayerCaches, positions, n_tok,
     path, traced identically to before."""
     B, T, _ = x.shape
     tap = None
-    h = apply_norm(lp["norm1"], x)
+    # the step's parts carry jax.named_scope names in their ops' metadata
+    # (attn / pool / evict / mlp), so a profile can charge each device op
+    # to one of them; the ops themselves are unchanged
+    with jax.named_scope(spec.mixer):
+        h = apply_norm(lp["norm1"], x)
     if spec.mixer == "attn":
-        q, k, v = attn_mod.project_qkv(lp["attn"], cfg, h,
-                                       jnp.maximum(positions, 0))
+        with jax.named_scope("attn"):
+            q, k, v = attn_mod.project_qkv(lp["attn"], cfg, h,
+                                           jnp.maximum(positions, 0))
         kvc: PagedLayerCache = cache.kv
-        # telemetry: the stats vector holds per-STEP counts — zero it at
-        # layer entry so collect_step_stats sees only this iteration
-        if kvc.stats is not None:
-            kvc = kvc._replace(stats=devstats.zeros())
-        # rows starting a new request free the previous occupant's pages
-        # back to the shared pool before their first chunk allocates
-        kvc = release_rows(kvc, reset_mask)
-        # prefix sharing: an adopting row maps the source row's resident
-        # prompt-prefix pages (ref_count bumped, prefill skips those tokens)
-        # before its first non-shared chunk appends — DESIGN.md §7
-        kvc = adopt_prefix(kvc, share_src, share_pages, enable=reset_mask)
-        score = policy.write_score(k, v, positions)         # (B, T)
-        kvc = append_chunk(kvc, k, v, positions, score, n_tok)
+        with jax.named_scope("pool"):
+            # telemetry: the stats vector holds per-STEP counts — zero it
+            # at layer entry so collect_step_stats sees only this iteration
+            if kvc.stats is not None:
+                kvc = kvc._replace(stats=devstats.zeros())
+            # rows starting a new request free the previous occupant's
+            # pages back to the shared pool before their first chunk
+            # allocates
+            kvc = release_rows(kvc, reset_mask)
+            # prefix sharing: an adopting row maps the source row's resident
+            # prompt-prefix pages (ref_count bumped, prefill skips those
+            # tokens) before its first non-shared chunk appends — DESIGN.md §7
+            kvc = adopt_prefix(kvc, share_src, share_pages, enable=reset_mask)
+            score = policy.write_score(k, v, positions)         # (B, T)
+            kvc = append_chunk(kvc, k, v, positions, score, n_tok)
         window = _spec_window(cfg, spec)
-        o, pscores = attn_mod.step_attention(
-            q, kvc, q_pos=positions, window=window, use_pallas=use_pallas,
-            decode_splits=decode_splits,
-            want_scores=fused_scores and use_pallas, tp_axis=tp_axis)
+        with jax.named_scope("attn"):
+            o, pscores = attn_mod.step_attention(
+                q, kvc, q_pos=positions, window=window, use_pallas=use_pallas,
+                decode_splits=decode_splits,
+                want_scores=fused_scores and use_pallas, tp_axis=tp_axis)
         if want_taps:
             tap = {"k": k, "v": v, "q": q, "o": o,
                    "live_pos": kvc.pos_view()}
@@ -416,18 +424,21 @@ def _step_layer(lp, cfg, spec, x, cache: LayerCaches, positions, n_tok,
         # skipped via lax.cond when their mask is all-False. When the fused
         # epilogue ran, both hooks rank pages by the scores the attention
         # pass already produced (DESIGN.md §8).
-        kvc = policy.post_write(kvc, ccfg, active=decode_mask,
-                                page_scores=pscores).cache
-        kvc = policy.chunk_prefill_evict(kvc, ccfg, active=prefill_mask,
-                                         window=window, page_scores=pscores)
-        o2 = o.reshape(B, T, -1) @ lp["attn"]["wo"]
-        if tp_axis is not None:
-            o2 = jax.lax.psum(o2, tp_axis)
-        x = x + o2
-        if cache.xattn is not None:
-            hx = apply_norm(lp["norm_x"], x)
-            x = x + attn_mod.cross_attention_forward(lp["xattn"], cfg, hx,
-                                                     cache.xattn)
+        with jax.named_scope("evict"):
+            kvc = policy.post_write(kvc, ccfg, active=decode_mask,
+                                    page_scores=pscores).cache
+            kvc = policy.chunk_prefill_evict(kvc, ccfg, active=prefill_mask,
+                                             window=window,
+                                             page_scores=pscores)
+        with jax.named_scope("attn"):
+            o2 = o.reshape(B, T, -1) @ lp["attn"]["wo"]
+            if tp_axis is not None:
+                o2 = jax.lax.psum(o2, tp_axis)
+            x = x + o2
+            if cache.xattn is not None:
+                hx = apply_norm(lp["norm_x"], x)
+                x = x + attn_mod.cross_attention_forward(lp["xattn"], cfg,
+                                                         hx, cache.xattn)
         cache = cache._replace(kv=kvc)
     elif spec.mixer == "mamba":
         m, st = _scan_recurrent(
@@ -455,16 +466,18 @@ def _step_layer(lp, cfg, spec, x, cache: LayerCaches, positions, n_tok,
             h, n_tok, reset_mask)
         x = x + m
         cache = cache._replace(slstm=st)
-    if spec.mlp == "dense":
-        h2 = apply_norm(lp["norm2"], x)
-        x = x + mlp_forward(lp["mlp"], cfg, h2, tp_axis=tp_axis)
-    elif spec.mlp == "moe":
-        # per-token dense-combine MoE: padding tokens cannot steal expert
-        # capacity from live ones, so results are chunking-invariant
-        h2 = apply_norm(lp["norm2"], x)
-        mo = moe_forward_decode(lp["moe"], cfg, h2.reshape(B * T, -1),
-                                tp_axis=tp_axis)
-        x = x + mo.reshape(B, T, -1)
+    with jax.named_scope("mlp"):
+        if spec.mlp == "dense":
+            h2 = apply_norm(lp["norm2"], x)
+            x = x + mlp_forward(lp["mlp"], cfg, h2, tp_axis=tp_axis)
+        elif spec.mlp == "moe":
+            # per-token dense-combine MoE: padding tokens cannot steal
+            # expert capacity from live ones, so results are
+            # chunking-invariant
+            h2 = apply_norm(lp["norm2"], x)
+            mo = moe_forward_decode(lp["moe"], cfg, h2.reshape(B * T, -1),
+                                    tp_axis=tp_axis)
+            x = x + mo.reshape(B, T, -1)
     return x, cache, tap
 
 
@@ -521,7 +534,8 @@ def forward_step(params, cfg: ModelConfig, tokens, n_tok, cache: ModelCache,
     the taps dict when ``want_taps``. Rows with n_tok == 0 return logits of
     stale garbage — callers mask.
     """
-    x = embed_tokens(params, cfg, tokens)                   # (B, T, D)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, cfg, tokens)               # (B, T, D)
     B, T = x.shape[0], x.shape[1]
     if decode_mask is None:
         decode_mask = jnp.zeros((B,), bool)
@@ -576,9 +590,10 @@ def forward_step(params, cfg: ModelConfig, tokens, n_tok, cache: ModelCache,
                                fused_scores, want_taps, tp_axis)
         tail_caches.append(c)
         tail_taps.append(tp)
-    last = jnp.maximum(n_tok - 1, 0)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    logits = lm_logits(params, cfg, x_last)
+    with jax.named_scope("logits"):
+        last = jnp.maximum(n_tok - 1, 0)
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        logits = lm_logits(params, cfg, x_last)
     out_cache = ModelCache(pattern=pattern_caches, tail=tail_caches,
                            cur_pos=cur_pos + n_tok)
     if want_taps:

@@ -6,8 +6,8 @@ Pieces, deliberately decoupled from each other and from the engine:
   histograms with real p50/p90/p99, snapshot-able to JSON and renderable
   as a text dashboard.
 - :mod:`repro.obs.trace` — buffered JSONL trace (schema v2: step / event /
-  probe records + version-dispatched validator) and optional
-  ``jax.profiler`` annotation scopes.
+  probe records + validator) and optional ``jax.profiler`` annotation
+  spans (the engine's host phases, DESIGN.md §9).
 - :mod:`repro.core.devstats` — the device half: the int32 stats vector
   the pool mutators accumulate inside the jitted step (no host callbacks
   on the hot path), reconciled into the registry once per step.
@@ -30,15 +30,15 @@ from dataclasses import dataclass, field
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                LATENCY_BOUNDS_S)
-from repro.obs.trace import (TRACE_SCHEMA, TRACE_SCHEMA_V1,
-                             TRACE_SCHEMA_VERSION, TraceWriter, annotation,
-                             validate_event, validate_file)
+from repro.obs.trace import (TRACE_SCHEMA, TRACE_SCHEMA_VERSION,
+                             TraceWriter, annotation, validate_event,
+                             validate_file)
 from repro.obs.timeline import TimelineRecorder
 from repro.obs.lineage import PageLineageLedger, StepPlanContext
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "LATENCY_BOUNDS_S",
-    "TRACE_SCHEMA", "TRACE_SCHEMA_V1", "TRACE_SCHEMA_VERSION", "TraceWriter",
+    "TRACE_SCHEMA", "TRACE_SCHEMA_VERSION", "TraceWriter",
     "annotation", "validate_event", "validate_file", "ObsConfig",
     "EngineObs", "TimelineRecorder", "PageLineageLedger", "StepPlanContext",
 ]
@@ -48,13 +48,15 @@ __all__ = [
 class ObsConfig:
     """What the engine should instrument.
 
-    metrics      : host registry + device stats vector (the ≤2%-overhead
-                   default-on path — BENCH_obs.json gates it)
+    metrics      : host registry + device stats vector (the default-on
+                   path; host time per step on a TPU v5e: PERF.md §5)
     trace_path   : write one JSONL record per step here (None == no trace);
                    lineage events and regret probes also land on this
                    stream when enabled
-    profiler_annotations : wrap plan/step in jax.profiler.TraceAnnotation
-                   scopes (off by default; only useful under a profiler)
+    profiler_annotations : record each engine step as a
+                   jax.profiler.TraceAnnotation span tiled by its host
+                   phase spans (DESIGN.md §9; off by default, only useful
+                   under a profiler)
     program_ceiling : compiled-program count the engine expects at steady
                    state; crossing it flips the unexpected_compile flag on
                    that step's trace event and bumps the sentinel counter

@@ -2,8 +2,8 @@
 
 Design constraints (DESIGN.md §9): every instrument is a few Python floats
 — ``observe()`` on the serving hot path is O(log n_buckets) with zero
-allocation, so the registry itself can never be the overhead the
-BENCH_obs gate measures. Histograms use FIXED log-spaced bucket bounds
+allocation (the engine's host time per step on a TPU v5e, registry
+included, is in PERF.md §5). Histograms use FIXED log-spaced bucket bounds
 (~100 us .. ~60 s, 8 per decade) chosen once at import: snapshots from
 different runs/processes are mergeable bucket-by-bucket, and quantiles
 come from linear interpolation inside the bucket (error bounded by the

@@ -3,8 +3,8 @@
 Schema **v2** (this file) carries three record kinds on one stream,
 discriminated by the required ``rec`` field:
 
-- ``rec == "step"``  — one per ``Engine.step`` iteration (same shape as the
-  v1 flat event, plus ``rec``).
+- ``rec == "step"``  — one per ``Engine.step`` iteration: step kind, batch
+  mix, tokens, host timings, per-step device pool counters.
 - ``rec == "event"`` — one per page-lineage mutation (alloc / adopt / fork /
   evict / release) observed on the tracked attention layer, with the
   physical page id, owner slot, logical page index, and the policy score
@@ -15,9 +15,8 @@ discriminated by the required ``rec`` field:
 
 Records are flat JSON objects so any tool (jq, pandas,
 ``benchmarks/roofline.py --obs``) can consume them without a reader
-library. :func:`validate_event` / :func:`validate_file` are the contract
-and version-dispatch: **v1 files stay valid** (a v1 record has ``v == 1``
-and no ``rec``; tests pin this on a checked-in fixture).
+library. :func:`validate_event` / :func:`validate_file` are the contract;
+a record of any other version is rejected.
 
 The writer buffers ``flush_every`` encoded lines before touching the file
 so the hot path pays one json.dumps per record and an amortized write —
@@ -27,10 +26,11 @@ normal interpreter exit still lands the buffered tail; the engine loop
 additionally flushes on error. SIGKILL can still lose at most
 ``flush_every - 1`` records — by design (no fsync on the hot path).
 
-``annotation(name)`` wraps a host region in ``jax.profiler.TraceAnnotation``
-when profiler annotations are enabled AND the jax build has them —
-otherwise it is a zero-cost nullcontext, so the engine can always write
-``with trace.annotation("engine.step"):`` unconditionally.
+``annotation(name, enabled)`` wraps a host region in
+``jax.profiler.TraceAnnotation`` when profiler annotations are enabled AND
+the jax build has them — otherwise it returns one shared no-op context (no
+import, no allocation), so the engine can always write
+``with trace.annotation("engine.wait", on):`` unconditionally.
 """
 from __future__ import annotations
 
@@ -45,11 +45,12 @@ TRACE_SCHEMA_VERSION = 2
 # schemas: field -> (type(s), required)
 # ---------------------------------------------------------------------------
 
-# v1 step event (PR 8). Integer counter fields are per-STEP deltas (device
-# stats vector summed over layers), not running totals; *_ms are host
-# wall-clock milliseconds. Kept verbatim for back-compat validation.
-TRACE_SCHEMA_V1: dict = {
+# Step record. Integer counter fields are per-STEP deltas (device stats
+# vector summed over layers), not running totals; *_ms are host wall-clock
+# milliseconds.
+TRACE_STEP_SCHEMA: dict = {
     "v": (int, True),               # schema version
+    "rec": (str, True),             # record kind: "step"
     "step": (int, True),            # engine step counter at emission
                                     # (monotonic, 1-based after each step)
     "kind": (str, True),            # "decode" | "mixed" | "prefill" | "idle"
@@ -77,10 +78,7 @@ TRACE_SCHEMA_V1: dict = {
     "finished": (int, True),        # requests retired this step
 }
 
-# v2 step record: v1 shape + the "rec" discriminator.
-TRACE_STEP_SCHEMA: dict = dict(TRACE_SCHEMA_V1, rec=(str, True))
-
-# v2 page-lineage event record. One per mutation of the tracked attention
+# Page-lineage event record. One per mutation of the tracked attention
 # layer's page pool, derived host-side (engine snapshot diff + step plan).
 TRACE_EVENT_SCHEMA: dict = {
     "v": (int, True),
@@ -98,7 +96,7 @@ TRACE_EVENT_SCHEMA: dict = {
     "pos": (int, False),            # first token position on the page
 }
 
-# v2 regret-probe record. One per sampled shadow probe (obs/regret.py):
+# Regret-probe record. One per sampled shadow probe (obs/regret.py):
 # lists are per-transformer-layer, index 0 == first attention layer.
 TRACE_PROBE_SCHEMA: dict = {
     "v": (int, True),
@@ -117,7 +115,7 @@ TRACE_PROBE_SCHEMA: dict = {
 # PR 8; keep it pointing at the current step-record shape.
 TRACE_SCHEMA = TRACE_STEP_SCHEMA
 
-_V2_SCHEMAS = {
+_SCHEMAS = {
     "step": TRACE_STEP_SCHEMA,
     "event": TRACE_EVENT_SCHEMA,
     "probe": TRACE_PROBE_SCHEMA,
@@ -147,25 +145,17 @@ def _check_fields(ev: dict, schema: dict) -> list:
 
 
 def validate_event(ev: dict) -> list:
-    """Return a list of schema violations (empty == valid).
-
-    Version-dispatched: ``v == 1`` (or absent, for pre-versioned files)
-    validates against the v1 step schema; ``v == 2`` dispatches on ``rec``.
-    """
+    """Return a list of schema violations (empty == valid): the record must
+    carry ``v == TRACE_SCHEMA_VERSION`` and a known ``rec``."""
     if not isinstance(ev, dict):
         return [f"event is {type(ev).__name__}, not object"]
-    v = ev.get("v", 1)
-    if v == 1:
-        errs = _check_fields(ev, TRACE_SCHEMA_V1)
-        if ev.get("kind") not in (None,) + _STEP_KINDS:
-            errs.append(f"bad kind {ev.get('kind')!r}")
-        return errs
+    v = ev.get("v")
     if v != TRACE_SCHEMA_VERSION:
-        return [f"schema version {v!r} not in (1, {TRACE_SCHEMA_VERSION})"]
+        return [f"schema version {v!r} is not {TRACE_SCHEMA_VERSION}"]
     rec = ev.get("rec")
-    schema = _V2_SCHEMAS.get(rec)
+    schema = _SCHEMAS.get(rec)
     if schema is None:
-        return [f"bad rec {rec!r} (want one of {sorted(_V2_SCHEMAS)})"]
+        return [f"bad rec {rec!r} (want one of {sorted(_SCHEMAS)})"]
     errs = _check_fields(ev, schema)
     if rec == "step" and ev.get("kind") not in (None,) + _STEP_KINDS:
         errs.append(f"bad kind {ev.get('kind')!r}")
@@ -175,7 +165,7 @@ def validate_event(ev: dict) -> list:
 
 
 def validate_file(path: str, max_errors: int = 20) -> list:
-    """Validate every line of a JSONL trace (v1 or v2); returns violations
+    """Validate every line of a JSONL trace; returns violations
     with line numbers (empty == valid file)."""
     errs = []
     with open(path) as f:
@@ -242,17 +232,21 @@ class TraceWriter:
         self.close()
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
 def annotation(name: str, enabled: bool = True):
     """Context manager: ``jax.profiler.TraceAnnotation(name)`` when enabled
-    and available, else a nullcontext. Lets device profiles line up with
-    host-side trace events without making jax.profiler a hard dependency."""
+    and available, else one shared no-op context. Lets device profiles line
+    up with host-side trace events without making jax.profiler a hard
+    dependency."""
     if not enabled:
-        return contextlib.nullcontext()
+        return _NO_SPAN
     try:
         import jax.profiler
         return jax.profiler.TraceAnnotation(name)
     except (ImportError, AttributeError):
-        return contextlib.nullcontext()
+        return _NO_SPAN
 
 
 def main(argv=None) -> int:
@@ -270,7 +264,7 @@ def main(argv=None) -> int:
     with open(args.path) as f:
         for line in f:
             ev = json.loads(line)
-            key = f"v{ev.get('v', 1)}:{ev.get('rec', 'step')}"
+            key = ev["rec"]
             counts[key] = counts.get(key, 0) + 1
     total = sum(counts.values())
     mix = " ".join(f"{k}={v}" for k, v in sorted(counts.items()))

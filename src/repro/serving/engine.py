@@ -35,11 +35,13 @@ compiled-program count against the known ceiling (2: T == chunk and
 T == 1) and flags any unexpected compile once through the trace. The
 legacy :class:`EngineStats` scalars and :meth:`Engine.pool_stats`
 (fleet-level pool occupancy, host-recomputed from ref counts) remain the
-benchmark-facing summaries; ``BENCH_obs.json`` gates the fully
-instrumented TPOT ladder at ≤2% overhead vs. instrumentation off.
+benchmark-facing summaries. Under a profiler, each step's host phases are
+profiler spans and the step program's parts are named scopes (see
+:meth:`Engine.step`); what they cost on a TPU v5e is in PERF.md §5.
 """
 from __future__ import annotations
 
+import functools
 import time
 import warnings
 from dataclasses import dataclass
@@ -88,6 +90,20 @@ class EngineStats:
     @property
     def decode_tok_per_s(self) -> float:
         return self.decode_tokens / self.decode_s if self.decode_s else 0.0
+
+
+# the step program's two jitted names (T == 1, T == chunk): profiles show
+# them as jit__step_decode / jit__step_mixed
+_PROGRAMS = ("_step_decode", "_step_mixed")
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``, the name ``jax.jit`` gives its program."""
+    @functools.wraps(fn)
+    def step(*args):
+        return fn(*args)
+    step.__name__ = step.__qualname__ = name
+    return step
 
 
 def init_params(cfg: ModelConfig, key, mesh=None):
@@ -161,8 +177,8 @@ class Engine:
         self._next_id = 0
 
         # telemetry (DESIGN.md §9): metrics default ON — the device stats
-        # vector + registry are the ≤2%-overhead path BENCH_obs.json gates.
-        # obs=ObsConfig(metrics=False) restores the bare pre-obs pytree.
+        # vector + registry. obs=ObsConfig(metrics=False) restores the bare
+        # pre-obs pytree.
         self.obs = EngineObs(obs if obs is not None else ObsConfig())
         self._t_start = time.perf_counter()
         self._programs_seen = 0
@@ -191,7 +207,8 @@ class Engine:
             self._init_tp(make_cache)
         else:
             self.cache: ModelCache = make_cache()
-            self._step_fn = jax.jit(self._step_impl)
+            self._step_decode, self._step_mixed = (
+                jax.jit(_named(self._step_impl, n)) for n in _PROGRAMS)
         self.cur_tokens = np.zeros((max_batch,), np.int32)
 
         # running pool occupancy, maintained from the device stats deltas
@@ -248,11 +265,12 @@ class Engine:
         # to the compiler, replicated leaves come back with an equivalent
         # but unequal spec, and the next step's call misses the jit cache
         rep_sharding = NamedSharding(mesh, rep)
-        self._step_fn = jax.jit(
-            _shard_map(self._step_impl, mesh, in_specs=in_specs,
-                       out_specs=out_specs, manual_axes=("data", "model")),
-            out_shardings=(rep_sharding, c_shardings, rep_sharding,
-                           rep_sharding))
+        step = _shard_map(self._step_impl, mesh, in_specs=in_specs,
+                          out_specs=out_specs, manual_axes=("data", "model"))
+        self._step_decode, self._step_mixed = (
+            jax.jit(_named(step, n), out_shardings=(
+                rep_sharding, c_shardings, rep_sharding, rep_sharding))
+            for n in _PROGRAMS)
 
     @staticmethod
     def _lineage_impl(cache: ModelCache):
@@ -269,9 +287,12 @@ class Engine:
     # ---------------------------------------------------------------- jitted
     def _step_impl(self, params, tokens, n_tok, decode_mask, prefill_mask,
                    reset_mask, share_src, share_pages, cache, key):
-        """The unified step: append + attend + evict + sample. Compiled once
-        per token-dim T — the engine only ever calls it with T == chunk_size
-        (mixed/prefill steps) and T == 1 (decode-only steps).
+        """The unified step: append + attend + evict + sample. Jitted twice,
+        under two program names (``jit__step_mixed`` for T == chunk_size
+        mixed/prefill steps, ``jit__step_decode`` for T == 1 decode-only
+        steps), so a profile tells the two apart by name. Its parts carry
+        ``jax.named_scope`` names (``pool``/``attn``/``evict``/``mlp`` in
+        each layer, ``sample`` and ``stats`` here) in the ops' metadata.
 
         Third output: the summed device stats vector ((devstats.NSTATS,)
         int32, this step's pool events across every attention layer), or
@@ -293,17 +314,21 @@ class Engine:
         logits, cache = out[0], out[1]
         taps = out[2] if self._want_taps else None
         s = self.sampling
-        next_tok = sample_tokens(key, logits, temperature=s.temperature,
-                                 top_k=s.top_k, top_p=s.top_p, greedy=s.greedy)
-        st = collect_step_stats(cache)
-        if st is not None and self._tp_axis is not None:
-            # sharding-aware devstats: metadata mutations run replicated on
-            # every shard, so a plain sum over the mesh would count each
-            # pool event tp times and break PR 8's conservation identities.
-            # Keep shard 0's vector and psum — a true mesh collective whose
-            # result still reconciles EXACTLY with host pool accounting.
-            idx = jax.lax.axis_index(self._tp_axis)
-            st = jax.lax.psum(jnp.where(idx == 0, st, 0), self._tp_axis)
+        with jax.named_scope("sample"):
+            next_tok = sample_tokens(key, logits, temperature=s.temperature,
+                                     top_k=s.top_k, top_p=s.top_p,
+                                     greedy=s.greedy)
+        with jax.named_scope("stats"):
+            st = collect_step_stats(cache)
+            if st is not None and self._tp_axis is not None:
+                # sharding-aware devstats: metadata mutations run replicated
+                # on every shard, so a plain sum over the mesh would count
+                # each pool event tp times and break the pool's conservation
+                # identities (DESIGN.md §9). Keep shard 0's vector and psum:
+                # a true mesh collective whose result still reconciles
+                # EXACTLY with host pool accounting.
+                idx = jax.lax.axis_index(self._tp_axis)
+                st = jax.lax.psum(jnp.where(idx == 0, st, 0), self._tp_axis)
         return next_tok, cache, st, taps
 
     def _prefix_probe(self, slot: int) -> int:
@@ -314,18 +339,24 @@ class Engine:
 
     # ------------------------------------------------------------------- api
     def submit(self, prompt: np.ndarray, *, max_new_tokens: int | None = None,
-               eos_token_id: int | None = None) -> Request:
+               eos_token_id: int | None = None,
+               arrival_time: float | None = None) -> Request:
+        """Queue a request. ``arrival_time`` (``time.perf_counter`` clock)
+        dates a request whose caller knows when it arrived; TTFT and queue
+        time count from it. Default: now."""
         assert 0 < len(prompt) <= self.max_prompt_len, (
             f"prompt len {len(prompt)} not in (0, {self.max_prompt_len}]")
         req = Request(request_id=self._next_id,
                       prompt=np.asarray(prompt, np.int32),
                       max_new_tokens=max_new_tokens or self.max_new_tokens,
                       eos_token_id=eos_token_id)
+        if arrival_time is not None:
+            req.arrival_time = arrival_time
         self._next_id += 1
         self.scheduler.add(req)
         if self.obs.timeline is not None:
             self.obs.timeline.request_submitted(req.request_id,
-                                                time.perf_counter())
+                                                req.arrival_time)
         return req
 
     def _on_admit(self, slot: int, req: Request) -> None:
@@ -408,159 +439,193 @@ class Engine:
 
     def step(self) -> bool:
         """One engine iteration: plan a unified step (admission + decode
-        tokens + prompt chunks) and run it. Returns whether work remains."""
+        tokens + prompt chunks) and run it. Returns whether work remains.
+
+        With ``ObsConfig.profiler_annotations`` the iteration is one
+        ``engine.step`` profiler span (metadata: kind, step, row counts)
+        tiled by its phases, in order: ``engine.plan``, ``engine.inputs``
+        (step arrays, key split, host-to-device copies), ``engine.launch``
+        (the asynchronous program call), ``engine.wait`` (``device_get`` of
+        the sampled tokens: the host blocked on the device),
+        ``engine.stats`` (stats-vector transfer and reconcile) and
+        ``engine.emit`` (tokens to requests, finishes, registry, trace
+        record, timeline, forensics)."""
+        on = self.obs.cfg.profiler_annotations
+        span = annotation("engine.step", on)
+        with span:
+            return self._step(span if on else None)
+
+    def _step(self, span) -> bool:
         oc = self.obs.cfg
+        on = span is not None
         t_plan0 = time.perf_counter()
-        with annotation("engine.plan", enabled=oc.profiler_annotations):
+        with annotation("engine.plan", on):
             plan = self.scheduler.plan()
-        plan_dt = time.perf_counter() - t_plan0
-        if oc.metrics:
-            self.obs.registry.histogram("engine.plan_s").observe(plan_dt)
+            plan_dt = time.perf_counter() - t_plan0
+            if oc.metrics:
+                self.obs.registry.histogram("engine.plan_s").observe(plan_dt)
         if plan.empty:
             if self.obs.writer is not None:
-                self._emit_trace("idle", plan, plan_dt, 0.0, 0, None, 0, False)
+                with annotation("engine.emit", on):
+                    self._emit_trace("idle", plan, plan_dt, 0.0, 0, None, 0,
+                                     False)
             return self.scheduler.has_work()
-        B = self.max_batch
-        T = self.chunk_size if plan.prefill else 1
-        tokens = np.zeros((B, T), np.int32)
-        n_tok = np.zeros((B,), np.int32)
-        decode_mask = np.zeros((B,), bool)
-        prefill_mask = np.zeros((B,), bool)
-        reset_mask = np.zeros((B,), bool)
-        reset_mask[plan.reset] = True
-        share_src = np.full((B,), -1, np.int32)
-        share_pages = np.zeros((B,), np.int32)
-        for slot, src, n_pages in plan.adopt:
-            share_src[slot] = src
-            share_pages[slot] = n_pages
-            self.stats.shared_prefix_hits += 1
-            self.stats.shared_prefix_tokens += n_pages * self.ccfg.page_size
-        for slot, req in plan.decode:
-            tokens[slot, 0] = self.cur_tokens[slot]
-            n_tok[slot] = 1
-            decode_mask[slot] = True
-        for slot, req, chunk, _ in plan.prefill:
-            tokens[slot, :len(chunk)] = chunk
-            n_tok[slot] = len(chunk)
-            prefill_mask[slot] = True
-            req.prefill_pos += len(chunk)
-
-        t0 = time.perf_counter()
-        self._key, sk = jax.random.split(self._key)
-        with annotation("engine.step", enabled=oc.profiler_annotations):
-            next_tok, self.cache, stats_dev, taps = self._step_fn(
-                self.params, jnp.asarray(tokens), jnp.asarray(n_tok),
-                jnp.asarray(decode_mask), jnp.asarray(prefill_mask),
-                jnp.asarray(reset_mask), jnp.asarray(share_src),
-                jnp.asarray(share_pages), self.cache, sk)
-            next_np = np.asarray(jax.device_get(next_tok))
-        dt = time.perf_counter() - t0
-        now = time.perf_counter()
-        unexpected = self._check_recompile()
-        self.stats.steps += 1
-        if plan.prefill:
-            self.stats.prefill_s += dt
-        else:
-            self.stats.decode_s += dt
-            self.stats.decode_steps += 1
-
-        # reconcile this step's device pool events (one (NSTATS,) transfer)
-        st = None
-        if stats_dev is not None:
-            st = np.asarray(jax.device_get(stats_dev))
-            self.stats.pages_evicted += int(st[devstats.PAGES_EVICTED])
-            self.stats.tokens_evicted += int(st[devstats.TOKENS_EVICTED])
-            self.stats.forced_evictions += int(st[devstats.FORCED_EVICTIONS])
-            self._free_pages_est += int(st[devstats.PAGES_FREED]) - \
-                int(st[devstats.PAGES_ALLOCATED])
-
-        # forensics (DESIGN.md §10) — all host-side, plan-contextualized.
-        # Runs BEFORE the finish loops below so slot -> request attribution
-        # still sees this step's owners.
-        step_no = self.stats.steps
-        lin_events = []
-        if self.obs.ledger is not None:
-            snap = jax.device_get(self._lineage_fn(self.cache))
-            ctx = StepPlanContext(
-                reset_slots=frozenset(plan.reset),
-                adopt={slot: (src, n_pages)
-                       for slot, src, n_pages in plan.adopt})
-            lin_events = self.obs.ledger.observe_step(step_no, snap, ctx)
-            if self.obs.writer is not None:
-                for evn in lin_events:
-                    self.obs.writer.emit(evn.to_record())
-        if taps is not None:
-            self._observe_regret(plan, taps, n_tok, step_no)
-        tl = self.obs.timeline
-        if tl is not None:
-            kind_tl = "mixed" if (plan.prefill and plan.decode) else (
+        with annotation("engine.inputs", on):
+            kind = "mixed" if (plan.prefill and plan.decode) else (
                 "prefill" if plan.prefill else "decode")
-            tl.engine_step(step_no, kind_tl, t0, dt,
-                           tokens=int(n_tok.sum()))
+            if on:
+                span.set_metadata(kind=kind, step=self.stats.steps + 1,
+                                  decode_rows=len(plan.decode),
+                                  prefill_rows=len(plan.prefill))
+            B = self.max_batch
+            T = self.chunk_size if plan.prefill else 1
+            tokens = np.zeros((B, T), np.int32)
+            n_tok = np.zeros((B,), np.int32)
+            decode_mask = np.zeros((B,), bool)
+            prefill_mask = np.zeros((B,), bool)
+            reset_mask = np.zeros((B,), bool)
+            reset_mask[plan.reset] = True
+            share_src = np.full((B,), -1, np.int32)
+            share_pages = np.zeros((B,), np.int32)
+            for slot, src, n_pages in plan.adopt:
+                share_src[slot] = src
+                share_pages[slot] = n_pages
+                self.stats.shared_prefix_hits += 1
+                self.stats.shared_prefix_tokens += \
+                    n_pages * self.ccfg.page_size
             for slot, req in plan.decode:
-                tl.decode_step(req.request_id, t0)
+                tokens[slot, 0] = self.cur_tokens[slot]
+                n_tok[slot] = 1
+                decode_mask[slot] = True
             for slot, req, chunk, _ in plan.prefill:
-                tl.prefill_chunk(req.request_id, t0, t0 + dt,
-                                 tokens=len(chunk), step=step_no)
-            if st is not None and int(st[devstats.PAGES_EVICTED]) > 0:
-                tl.engine_instant(now, "pages_evicted",
-                                  count=int(st[devstats.PAGES_EVICTED]))
-            for evn in lin_events:
-                if evn.etype == "evict":
-                    owner = self.scheduler.slots[evn.slot]
-                    if owner is not None:
-                        tl.request_evicted_page(owner.request_id, now,
-                                                page=evn.page, lpi=evn.lpi,
-                                                score=evn.score)
+                tokens[slot, :len(chunk)] = chunk
+                n_tok[slot] = len(chunk)
+                prefill_mask[slot] = True
+                req.prefill_pos += len(chunk)
 
-        reg = self.obs.registry if oc.metrics else None
-        if reg is not None:
-            reg.histogram("engine.step_wall_s").observe(dt)
-            reg.counter("engine.steps").inc()
-            reg.counter("engine.tokens").inc(int(n_tok.sum()))
-            if st is not None:
-                for i, name in enumerate(devstats.STAT_NAMES):
-                    reg.counter(f"pool.{name}").inc(int(st[i]))
-                reg.gauge("pool.free_pages").set(self._free_pages_est)
-                reg.gauge("pool.total_pages").set(self._pool_pages_total)
-            for slot in plan.reset:
-                r = self.scheduler.slots[slot]
-                if r is not None:
-                    reg.histogram("engine.queue_s").observe(r.queue_time)
+            # EngineStats times t0 .. dt: key split, copies, program, wait
+            t0 = time.perf_counter()
+            self._key, sk = jax.random.split(self._key)
+            inputs = [jnp.asarray(a) for a in (
+                tokens, n_tok, decode_mask, prefill_mask, reset_mask,
+                share_src, share_pages)]
+            program = self._step_mixed if plan.prefill else self._step_decode
+        with annotation("engine.launch", on):
+            next_tok, self.cache, stats_dev, taps = program(
+                self.params, *inputs, self.cache, sk)
+        with annotation("engine.wait", on):
+            next_np = np.asarray(jax.device_get(next_tok))
+            dt = time.perf_counter() - t0
+            now = time.perf_counter()
 
-        finished_before = len(self.scheduler.finished)
-        for slot, req in plan.decode:
-            req.output_tokens.append(int(next_np[slot]))
-            req.decode_times.append(dt)
-            self.cur_tokens[slot] = next_np[slot]
-            self.stats.tokens_generated += 1
-            if not plan.prefill:
-                self.stats.decode_tokens += 1
+        with annotation("engine.stats", on):
+            unexpected = self._check_recompile()
+            self.stats.steps += 1
+            if plan.prefill:
+                self.stats.prefill_s += dt
+            else:
+                self.stats.decode_s += dt
+                self.stats.decode_steps += 1
+            # reconcile this step's device pool events (one (NSTATS,)
+            # transfer)
+            st = None
+            if stats_dev is not None:
+                st = np.asarray(jax.device_get(stats_dev))
+                self.stats.pages_evicted += int(st[devstats.PAGES_EVICTED])
+                self.stats.tokens_evicted += int(st[devstats.TOKENS_EVICTED])
+                self.stats.forced_evictions += \
+                    int(st[devstats.FORCED_EVICTIONS])
+                self._free_pages_est += int(st[devstats.PAGES_FREED]) - \
+                    int(st[devstats.PAGES_ALLOCATED])
+
+        with annotation("engine.emit", on):
+            # forensics (DESIGN.md §10) — all host-side, plan-
+            # contextualized. Runs BEFORE the finish loops below so slot ->
+            # request attribution still sees this step's owners.
+            step_no = self.stats.steps
+            lin_events = []
+            if self.obs.ledger is not None:
+                snap = jax.device_get(self._lineage_fn(self.cache))
+                ctx = StepPlanContext(
+                    reset_slots=frozenset(plan.reset),
+                    adopt={slot: (src, n_pages)
+                           for slot, src, n_pages in plan.adopt})
+                lin_events = self.obs.ledger.observe_step(step_no, snap,
+                                                          ctx)
+                if self.obs.writer is not None:
+                    for evn in lin_events:
+                        self.obs.writer.emit(evn.to_record())
+            if taps is not None:
+                self._observe_regret(plan, taps, n_tok, step_no)
+            tl = self.obs.timeline
+            if tl is not None:
+                tl.engine_step(step_no, kind, t0, dt,
+                               tokens=int(n_tok.sum()))
+                for slot, req in plan.decode:
+                    tl.decode_step(req.request_id, t0)
+                for slot, req, chunk, _ in plan.prefill:
+                    tl.prefill_chunk(req.request_id, t0, t0 + dt,
+                                     tokens=len(chunk), step=step_no)
+                if st is not None and int(st[devstats.PAGES_EVICTED]) > 0:
+                    tl.engine_instant(now, "pages_evicted",
+                                      count=int(st[devstats.PAGES_EVICTED]))
+                for evn in lin_events:
+                    if evn.etype == "evict":
+                        owner = self.scheduler.slots[evn.slot]
+                        if owner is not None:
+                            tl.request_evicted_page(
+                                owner.request_id, now, page=evn.page,
+                                lpi=evn.lpi, score=evn.score)
+
+            reg = self.obs.registry if oc.metrics else None
             if reg is not None:
-                reg.histogram("engine.itl_s").observe(dt)
-            self._maybe_finish(req)
-        for slot, req, chunk, completes in plan.prefill:
-            req.prefill_time += dt
-            if completes:
-                # the sampled token at the prompt's last position is this
-                # request's FIRST output token (its TTFT moment, dated from
-                # ARRIVAL — an adopter's shorter prefill must not hide its
-                # queueing/deferral time; see Request.ttft)
+                reg.histogram("engine.step_wall_s").observe(dt)
+                reg.counter("engine.steps").inc()
+                reg.counter("engine.tokens").inc(int(n_tok.sum()))
+                if st is not None:
+                    for i, name in enumerate(devstats.STAT_NAMES):
+                        reg.counter(f"pool.{name}").inc(int(st[i]))
+                    reg.gauge("pool.free_pages").set(self._free_pages_est)
+                    reg.gauge("pool.total_pages").set(
+                        self._pool_pages_total)
+                for slot in plan.reset:
+                    r = self.scheduler.slots[slot]
+                    if r is not None:
+                        reg.histogram("engine.queue_s").observe(
+                            r.queue_time)
+
+            finished_before = len(self.scheduler.finished)
+            for slot, req in plan.decode:
                 req.output_tokens.append(int(next_np[slot]))
-                req.first_token_time = now
+                req.decode_times.append(dt)
                 self.cur_tokens[slot] = next_np[slot]
-                req.status = RequestStatus.RUNNING
                 self.stats.tokens_generated += 1
+                if not plan.prefill:
+                    self.stats.decode_tokens += 1
                 if reg is not None:
-                    reg.histogram("engine.ttft_s").observe(
-                        now - req.arrival_time)
+                    reg.histogram("engine.itl_s").observe(dt)
                 self._maybe_finish(req)
-        if self.obs.writer is not None:
-            kind = "mixed" if (plan.prefill and plan.decode) else \
-                ("prefill" if plan.prefill else "decode")
-            self._emit_trace(kind, plan, plan_dt, dt, int(n_tok.sum()), st,
-                             len(self.scheduler.finished) - finished_before,
-                             unexpected)
+            for slot, req, chunk, completes in plan.prefill:
+                req.prefill_time += dt
+                if completes:
+                    # the sampled token at the prompt's last position is
+                    # this request's FIRST output token (its TTFT moment,
+                    # dated from ARRIVAL — an adopter's shorter prefill must
+                    # not hide its queueing/deferral time; see Request.ttft)
+                    req.output_tokens.append(int(next_np[slot]))
+                    req.first_token_time = now
+                    self.cur_tokens[slot] = next_np[slot]
+                    req.status = RequestStatus.RUNNING
+                    self.stats.tokens_generated += 1
+                    if reg is not None:
+                        reg.histogram("engine.ttft_s").observe(
+                            now - req.arrival_time)
+                    self._maybe_finish(req)
+            if self.obs.writer is not None:
+                self._emit_trace(
+                    kind, plan, plan_dt, dt, int(n_tok.sum()), st,
+                    len(self.scheduler.finished) - finished_before,
+                    unexpected)
         return self.scheduler.has_work()
 
     def _observe_regret(self, plan, taps, n_tok, step_no: int) -> None:
@@ -636,8 +701,11 @@ class Engine:
         recompilation family is dead: expect 2 — T == chunk and T == 1).
         The recompile sentinel mirrors this into the ``engine.programs``
         gauge and counts ceiling crossings in ``engine.unexpected_compiles``."""
-        size = getattr(self._step_fn, "_cache_size", None)
-        return int(size()) if callable(size) else -1
+        sizes = [getattr(f, "_cache_size", None)
+                 for f in (self._step_decode, self._step_mixed)]
+        if not all(callable(size) for size in sizes):
+            return -1
+        return sum(int(size()) for size in sizes)
 
     def metrics_snapshot(self) -> dict:
         """JSON-safe snapshot of every metric (see MetricsRegistry)."""

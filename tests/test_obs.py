@@ -125,7 +125,9 @@ def test_trace_roundtrip_and_validation(tmp_path):
 
 def test_trace_validator_catches_bad_events(tmp_path):
     assert validate_event(_event()) == []
-    assert any("missing" in e for e in validate_event({"v": 1}))
+    assert any("missing" in e for e in validate_event(
+        {"v": TRACE_SCHEMA_VERSION, "rec": "step"}))
+    assert any("is not" in e for e in validate_event({"v": 1}))
     assert any("kind" in e for e in validate_event(_event(kind="bogus")))
     assert any("unknown" in e for e in validate_event(_event(zzz=1)))
     assert any("expected int" in e for e in validate_event(_event(tokens=1.5)))

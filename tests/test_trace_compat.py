@@ -1,10 +1,9 @@
-"""Trace schema v2 back-compat + writer crash safety (ISSUE 9 satellites).
+"""Trace schema v2 on a checked-in fixture + writer crash safety.
 
-- a checked-in **v1** trace fixture (PR 8's schema, pre-``rec``) stays
-  valid under the version-dispatched validator, the CLI, and the
-  ``roofline.py --obs`` summary path;
-- v2 rejects what it must (bad version, bad rec) while the step record
-  remains the v1 shape + discriminator;
+- a checked-in v2 step-record fixture stays valid under the validator, the
+  CLI, and the ``roofline.py --obs`` summary path;
+- the validator rejects what it must: a v1 record (no ``rec``), unknown
+  versions, a bad ``rec``;
 - TraceWriter lands the buffered tail when the process dies on an
   unhandled exception (atexit fallback, exercised in a subprocess) and
   when the engine loop errors mid-run (flush-on-error).
@@ -18,21 +17,21 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.obs.trace import (TRACE_SCHEMA_V1, TRACE_STEP_SCHEMA,
-                             validate_event, validate_file)
+from repro.obs.trace import validate_event, validate_file
 from repro.obs.trace import main as trace_main
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
-                       "trace_v1.jsonl")
+                       "trace_v2.jsonl")
 
 
 # ---------------------------------------------------------------------------
-# v1 back-compat on the checked-in fixture
+# the checked-in fixture
 # ---------------------------------------------------------------------------
 
-def test_v1_fixture_validates():
+def test_v1_fixture_validates(capsys):
     assert validate_file(FIXTURE) == []
     assert trace_main([FIXTURE]) == 0
+    assert "5 records (step=5)" in capsys.readouterr().out
 
 
 def test_v1_fixture_summarizes_in_roofline(capsys):
@@ -46,49 +45,26 @@ def test_v1_fixture_summarizes_in_roofline(capsys):
     decode = next(r for r in rows if r["kind"] == "decode")
     assert decode["steps"] == 2
     assert decode["tokens_per_step"] == pytest.approx(2.5)
-    # optional devstat fields may be absent on v1 records (obs off)
+    # optional devstat fields may be absent (obs off)
     assert decode["pages_churn_per_step"] == pytest.approx(1.0)
-
-
-def test_v2_schema_is_v1_plus_discriminator():
-    """The step record is structurally v1 + ``rec`` — nothing renamed or
-    retyped, so v1 consumers keep working on v2 step records minus the one
-    extra key."""
-    assert set(TRACE_STEP_SCHEMA) - set(TRACE_SCHEMA_V1) == {"rec"}
-    for key, spec in TRACE_SCHEMA_V1.items():
-        assert TRACE_STEP_SCHEMA[key] == spec
 
 
 def test_version_dispatch():
     with open(FIXTURE) as f:
-        v1 = json.loads(f.readline())
-    assert validate_event(v1) == []
-    # an unversioned record (pre-PR-8 prototype files) validates as v1
-    unversioned = dict(v1)
-    del unversioned["v"]
-    assert validate_event(unversioned) == [] or \
-        validate_event(unversioned) == ["missing required field 'v'"]
-    # v1 does not accept v2-only fields
-    assert any("unknown" in e for e in validate_event(dict(v1, rec="step")))
+        v2 = json.loads(f.readline())
+    assert validate_event(v2) == []
+    # a v1 record (the flat step event before ``rec``: v == 1) is
+    # rejected, with or without its version field
+    v1 = {k: v for k, v in v2.items() if k != "rec"}
+    assert any("is not 2" in e for e in validate_event(dict(v1, v=1)))
+    unversioned = {k: v for k, v in v1.items() if k != "v"}
+    assert any("is not 2" in e for e in validate_event(unversioned))
     # v2 requires the discriminator, and rejects unknown versions
-    v2 = dict(v1, v=2)
-    assert any("bad rec" in e for e in validate_event(v2))
-    assert validate_event(dict(v2, rec="step")) == []
-    assert any("not in" in e for e in validate_event(dict(v1, v=3)))
-
-
-def test_mixed_v1_v2_file_validates(tmp_path):
-    """A file that grew across the version bump (v1 head, v2 tail) stays
-    valid line-by-line."""
-    with open(FIXTURE) as f:
-        lines = f.read().splitlines()
-    v2_step = json.dumps(dict(json.loads(lines[0]), v=2, rec="step"))
-    v2_event = json.dumps({"v": 2, "rec": "event", "step": 9,
-                           "etype": "evict", "page": 3, "slot": 0, "lpi": 1,
-                           "score": 0.5})
-    p = tmp_path / "mixed.jsonl"
-    p.write_text("\n".join(lines + [v2_step, v2_event]) + "\n")
-    assert validate_file(str(p)) == []
+    assert any("bad rec" in e for e in validate_event(dict(v1, v=2)))
+    assert any("bad rec" in e
+               for e in validate_event(dict(v2, rec="span")))
+    assert any("is not 2" in e for e in validate_event(dict(v2, v=3)))
+    assert any("unknown" in e for e in validate_event(dict(v2, extra=1)))
 
 
 # ---------------------------------------------------------------------------
