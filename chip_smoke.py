@@ -103,11 +103,15 @@ def serve(cfg, params, *, prompts, new_tokens: int, max_batch: int,
         f"{steady_tokens / steady_s if steady_s else 0.0:.1f}"
         f" programs={eng.num_compiled_programs()}")
     say(f"pages_evicted={s.pages_evicted} tokens_evicted={s.tokens_evicted}"
-        f" forced_evictions={s.forced_evictions}")
+        f" forced_evictions={s.forced_evictions}"
+        f" chunk_append_fallbacks={s.chunk_append_fallbacks}")
     check(len(done) == len(prompts), "not every request finished")
     check(all(r.num_generated == new_tokens for r in done),
           "a request stopped short of its token count")
     check(s.pages_evicted > 0, "no page was evicted")
+    check(s.chunk_append_fallbacks == 0,
+          "a chunk took the per-token append: structured eviction never "
+          "runs a row out of slots or the pool dry")
     check(eng.num_compiled_programs() == 2,
           f"expected 2 step programs, got {eng.num_compiled_programs()}")
     return eng

@@ -28,7 +28,9 @@ over attention layers):
     PAGES_EVICTED     policy page-level evictions (incl. forced)
     TOKENS_EVICTED    token-level evictions that invalidated a live token
     FORCED_EVICTIONS  fragmentation force-evicts (rollover found no free page)
-    TOKENS_WRITTEN    write_token appends that landed
+    TOKENS_WRITTEN    tokens appended that landed (write_token, append_chunk)
+    CHUNK_APPEND_FALLBACKS  append_chunk calls that took the per-token loop
+                      (a row had to force-evict; 0 under structured eviction)
 
 Conservation identities (exact; tests/test_obs.py checks them against
 host-recomputed pool state every step of a churned mixed workload):
@@ -54,12 +56,13 @@ PAGES_EVICTED = 5
 TOKENS_EVICTED = 6
 FORCED_EVICTIONS = 7
 TOKENS_WRITTEN = 8
-NSTATS = 9
+CHUNK_APPEND_FALLBACKS = 9
+NSTATS = 10
 
 STAT_NAMES = (
     "pages_allocated", "pages_freed", "pages_released", "pages_adopted",
     "pages_forked", "pages_evicted", "tokens_evicted", "forced_evictions",
-    "tokens_written",
+    "tokens_written", "chunk_append_fallbacks",
 )
 
 

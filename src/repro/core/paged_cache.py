@@ -698,6 +698,111 @@ def chunk_rollover(cache: PagedLayerCache, need) -> PagedLayerCache:
                     (cache, need))
 
 
+def _without_pool(cache: PagedLayerCache) -> PagedLayerCache:
+    """The cache with its payload (k, v, int8 scales) emptied to zero-size
+    arrays: what a ``lax.cond`` over the allocator may carry — a branch
+    that takes the pool makes XLA copy it. Shapes and page size hold."""
+    N, page = cache.pos.shape
+    empty = jnp.zeros((N, page, 0, 0), cache.k.dtype)
+    return cache._replace(k=empty, v=empty, k_scale=None, v_scale=None)
+
+
+def _allocate_chunk(meta: PagedLayerCache, rolls_at_0, fresh, t_roll,
+                    fits):
+    """Reclaim once, then map every fresh page of a chunk in one pass.
+
+    ``meta``: :func:`_without_pool` of the cache; ``rolls_at_0`` (B,) rows
+    parked full that roll over at the chunk's first token; ``fresh``
+    (B, K) fresh page k of row b is needed; ``t_roll`` (B, K) the token at
+    which it is opened; ``fits`` (() bool) the plan's other condition.
+    Returns (meta', phys (B, K), fits'): fresh page k of row b is pool page
+    ``phys[b, k]``, mapped at the row's k-th unmapped slot; ``fits'`` adds
+    that every row has the slots and the pool the pages (else the
+    per-token form must force-evict, and ``meta`` comes back as it was)."""
+    B, P = meta.block_table.shape
+    K = fresh.shape[1]
+    b = jnp.arange(B)
+    c = reclaim_empty_pages(meta, include_current=rolls_at_0)
+    unmapped = ~c.mapped_mask()                                   # (B, P)
+    need = jnp.sum(fresh, axis=1)
+    fits &= (jnp.all(need <= jnp.sum(unmapped, axis=1))
+             & (jnp.sum(need) <= c.num_free()))
+    # at one token rows allocate in row order: rank by (token, row)
+    key = jnp.where(fresh, t_roll * B + b[:, None], jnp.iinfo(jnp.int32).max)
+    order = jnp.argsort(key.reshape(-1))
+    c, phys, _ = alloc_pages(c, fresh.reshape(-1)[order])
+    phys = jnp.zeros_like(phys).at[order].set(phys).reshape(B, K)
+    # the k-th unmapped slot of each row (rank K and beyond: dropped)
+    rank = jnp.where(unmapped, jnp.cumsum(unmapped, axis=1) - 1, K)
+    slot = jnp.zeros((B, K), jnp.int32).at[b[:, None], rank].set(
+        jnp.arange(P, dtype=jnp.int32)[None])
+    last = jnp.maximum(need - 1, 0)
+    c = c._replace(
+        block_table=c.block_table.at[b[:, None], jnp.where(fresh, slot, P)]
+        .set(phys),
+        cur_page=jnp.where(need > 0, slot[b, last], c.cur_page))
+    return jax.tree.map(lambda x, y: jnp.where(fits, x, y), c, meta), \
+        phys, fits
+
+
+def _allocate_none(meta: PagedLayerCache, rolls_at_0, fresh, t_roll, fits):
+    """:func:`_allocate_chunk` where no row opens a fresh page: nothing."""
+    del rolls_at_0, t_roll
+    return meta, jnp.zeros(fresh.shape, jnp.int32), fits
+
+
+def _plan_chunk(cache: PagedLayerCache, pos_chunk, n_tok):
+    """What the per-token loop of :func:`append_chunk` does to the pool's
+    metadata, in closed form.
+
+    Row b's head page takes its first ``page - cur_off`` tokens; its k-th
+    rollover comes at token ``(page - cur_off) + k*page`` and maps a fresh
+    page at its k-th unmapped slot. The loop reclaims empty pages at its
+    first rollover (once: nothing empties mid-chunk) and, at one token,
+    allocates in row order, so the fresh pages go out in (token, row)
+    order — in one :func:`_allocate_chunk`, skipped where no row rolls.
+    Returns ``(head, tgt, off, fits)``: ``fits`` (() bool) says whether the
+    plan IS the loop's result — no row force-evicts, and no written token
+    has ``pos < 0`` (its page could empty and be reclaimed mid-chunk);
+    then ``head`` holds the metadata fields after the chunk and
+    ``tgt``/``off`` (B, T) each token's pool page (N where it is not
+    written) and offset, else the fields as they were and every ``tgt``
+    N."""
+    B, T = pos_chunk.shape
+    page, N = cache.page_size, cache.pool_pages
+    K = -(-T // page)                         # most fresh pages a row needs
+    b = jnp.arange(B)
+    t = jnp.arange(T)
+    head_phys = cache.block_table[b, cache.cur_page]
+    off0 = cache.cur_off
+    # a head on an unmapped slot with room left drops every write
+    # (write_token), so that row never rolls over either
+    n = jnp.where((head_phys < 0) & (off0 < page), 0, jnp.clip(n_tok, 0, T))
+    need = (jnp.maximum(off0 + n - page, 0) + page - 1) // page   # (B,)
+    fresh = jnp.arange(K)[None] < need[:, None]                   # (B, K)
+    t_roll = (page - off0)[:, None] + jnp.arange(K)[None] * page
+    active = t[None] < n[:, None]                                 # (B, T)
+    meta, phys, fits = lax.cond(
+        jnp.any(need > 0), _allocate_chunk, _allocate_none,
+        _without_pool(cache), (n > 0) & (off0 >= page), fresh, t_roll,
+        ~jnp.any(active & (pos_chunk < 0)))
+    active &= fits
+    # token t: head page at cur_off + t, else fresh page j // page at j % page
+    j = t[None] - (page - off0)[:, None]                          # (B, T)
+    in_head = j < 0
+    fresh_phys = jnp.take_along_axis(phys, jnp.clip(j // page, 0, K - 1), 1)
+    tgt = jnp.where(active, jnp.where(in_head, head_phys[:, None], fresh_phys),
+                    N)
+    off = jnp.where(in_head, off0[:, None] + t[None], j % page)
+    head = dict(
+        pos=meta.pos, score=meta.score, block_table=meta.block_table,
+        ref_count=meta.ref_count, cur_page=meta.cur_page,
+        cur_off=jnp.where(~fits, off0, jnp.where(
+            need > 0, (off0 + n - 1) % page + 1, off0 + n)).astype(jnp.int32),
+        stats=devstats.bump(meta.stats, devstats.TOKENS_WRITTEN, active))
+    return head, tgt, off, fits
+
+
 def append_chunk(cache: PagedLayerCache, k_chunk, v_chunk, pos_chunk,
                  score_chunk, n_tok) -> PagedLayerCache:
     """Append up to T tokens per request at the write head, allocating fresh
@@ -713,19 +818,44 @@ def append_chunk(cache: PagedLayerCache, k_chunk, v_chunk, pos_chunk,
     of the paper's Alg. 2), so a row transiently holds up to
     budget + chunk tokens. A decode row is just the T == 1 (or n_tok == 1)
     case of the same op — the unified step program has no separate insert
-    or prefill write path."""
-    B, T = pos_chunk.shape
+    or prefill write path.
 
-    def body(c, xs):
-        k_t, v_t, p_t, s_t, t = xs
+    The chunk is planned in closed form (:func:`_plan_chunk`: reclaim once,
+    allocate every fresh page in one pass) and written with one scatter
+    per pool array (int8 pools quantize the whole chunk: scales are per
+    token and head). That is bit-identical to writing token by token — a
+    lazy :func:`chunk_rollover` and a :func:`write_token` per slot — which
+    runs instead only where a row must force-evict (unstructured survivors
+    can pin every slot); ``CHUNK_APPEND_FALLBACKS`` counts those calls. The
+    per-token loop's trip count is 0 when the plan fits, where a
+    ``lax.cond`` holding the pool would copy it."""
+    T = pos_chunk.shape[1]
+    head, tgt, off, fits = _plan_chunk(cache, pos_chunk, n_tok)
+    cache = cache._replace(**head)
+
+    def put(dst, val):
+        return dst.at[tgt, off].set(val.astype(dst.dtype))
+
+    if cache.quantized:
+        kq, ks = quantize_absmax(k_chunk)
+        vq, vs = quantize_absmax(v_chunk)
+        cache = cache._replace(k=put(cache.k, kq), v=put(cache.v, vq),
+                               k_scale=put(cache.k_scale, ks),
+                               v_scale=put(cache.v_scale, vs))
+    else:
+        cache = cache._replace(k=put(cache.k, k_chunk), v=put(cache.v, v_chunk))
+    cache = cache._replace(pos=put(cache.pos, pos_chunk),
+                           score=put(cache.score, score_chunk))
+
+    def body(t, c):
         act = t < n_tok
         c = chunk_rollover(c, act & (c.cur_off >= c.page_size))
-        return write_token(c, k_t, v_t, p_t, s_t, active=act), None
+        return write_token(c, k_chunk[:, t], v_chunk[:, t], pos_chunk[:, t],
+                           score_chunk[:, t], active=act)
 
-    xs = (jnp.swapaxes(k_chunk, 0, 1), jnp.swapaxes(v_chunk, 0, 1),
-          pos_chunk.T, score_chunk.T, jnp.arange(T))
-    cache, _ = lax.scan(body, cache, xs)
-    return cache
+    cache = lax.fori_loop(0, jnp.where(fits, 0, T), body, cache)
+    return cache._replace(stats=devstats.bump(
+        cache.stats, devstats.CHUNK_APPEND_FALLBACKS, ~fits))
 
 
 # ---------------------------------------------------------------------------

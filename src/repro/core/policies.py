@@ -119,6 +119,16 @@ def _rollover_to_free_page(cache: PagedLayerCache, need):
                         (cache, need))
 
 
+def _page_age(cache: PagedLayerCache) -> jax.Array:
+    """(B, P) int32 — the position in each logical page's first slot; for a
+    full page, the only kind page eviction ranks, its oldest, as pages fill
+    in position order. The tie-break of page eviction: equal mean scores
+    go to the OLDER page, as an eviction order over positions has it; slot
+    order is no order at all, since freed slots are refilled as pages
+    come."""
+    return cache.pos[cache._phys(), 0]
+
+
 def _rollover_noop(args):
     cache, need = args
     return cache, jnp.zeros((cache.batch,), bool)
@@ -348,7 +358,7 @@ class PagedEviction(EvictionPolicy):
         m = jnp.maximum(jnp.sum(full, axis=-1) - cfg.budget_pages, 0)  # (B,)
         pscores = cache.page_scores() if page_scores is None else page_scores
         cand = jnp.where(full, pscores, jnp.inf)
-        order = jnp.argsort(cand, axis=-1)
+        order = jnp.lexsort((_page_age(cache), cand), axis=-1)
         ranks = jnp.argsort(order, axis=-1)                 # 0 == worst
         evict = full & (ranks < m[:, None]) & active[:, None]
         cache = evict_pages_mask(cache, evict)
@@ -372,7 +382,10 @@ class PagedEviction(EvictionPolicy):
             cur = jax.nn.one_hot(cache.cur_page, P, dtype=bool)
             full_pages &= ~cur
         cand = jnp.where(full_pages, pscores, jnp.inf)
-        victim = jnp.argmin(cand, axis=-1).astype(jnp.int32)
+        lowest = cand == jnp.min(cand, axis=-1, keepdims=True)
+        victim = jnp.argmin(jnp.where(lowest, _page_age(cache),
+                                      jnp.iinfo(jnp.int32).max),
+                            axis=-1).astype(jnp.int32)
         vscore = jnp.take_along_axis(pscores, victim[:, None],
                                      axis=-1)[:, 0].astype(jnp.float32)
         cache = evict_page(cache, victim, enable=do_evict)

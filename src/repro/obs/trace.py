@@ -71,6 +71,7 @@ TRACE_STEP_SCHEMA: dict = {
     "pages_evicted": (int, False),
     "tokens_evicted": (int, False),
     "forced_evictions": (int, False),
+    "chunk_append_fallbacks": (int, False),
     "pool_pages": (int, False),     # physical pool size (per layer)
     "free_pages": (int, False),     # engine's running free-list estimate
     "programs": (int, True),        # compiled-program cache size (sentinel)

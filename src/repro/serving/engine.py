@@ -82,6 +82,8 @@ class EngineStats:
     pages_evicted: int = 0
     tokens_evicted: int = 0
     forced_evictions: int = 0
+    chunk_append_fallbacks: int = 0   # append_chunk calls that took the
+                                      # per-token loop (summed over layers)
     shared_prefix_hits: int = 0   # admissions that adopted resident pages
     shared_prefix_tokens: int = 0  # prompt tokens whose prefill was skipped
     prefill_s: float = 0.0
@@ -535,6 +537,8 @@ class Engine:
                 self.stats.tokens_evicted += int(st[devstats.TOKENS_EVICTED])
                 self.stats.forced_evictions += \
                     int(st[devstats.FORCED_EVICTIONS])
+                self.stats.chunk_append_fallbacks += \
+                    int(st[devstats.CHUNK_APPEND_FALLBACKS])
                 self._free_pages_est += int(st[devstats.PAGES_FREED]) - \
                     int(st[devstats.PAGES_ALLOCATED])
 

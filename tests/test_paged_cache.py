@@ -2,7 +2,9 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from repro.core import devstats
 from repro.core import paged_cache as pc
 
 
@@ -147,33 +149,123 @@ def test_write_prompt_pages_layout():
     assert len(mapped) == len(set(mapped.tolist()))
 
 
-def test_append_chunk_matches_sequential_writes():
+def _seq_append(c, k, v, pos, score, n_tok):
+    """The per-token reference: a lazy rollover, then one write, per slot."""
+    for t in range(pos.shape[1]):
+        act = t < n_tok
+        c = pc.chunk_rollover(c, act & (c.cur_off >= c.page_size))
+        c = pc.write_token(c, k[:, t], v[:, t], pos[:, t], score[:, t],
+                           active=act)
+    return c
+
+
+def _chunk(c, n_tok, T, seed):
+    """Random k/v/score for a (B, T) chunk; positions continue each row."""
+    B = c.batch
+    KV, hd = c.k.shape[2], c.k.shape[3]
+    rng = jax.random.PRNGKey(seed)
+    k = jax.random.normal(rng, (B, T, KV, hd))
+    v = jax.random.normal(jax.random.fold_in(rng, 1), (B, T, KV, hd))
+    score = jax.random.normal(jax.random.fold_in(rng, 2), (B, T))
+    n_tok = jnp.asarray(n_tok, jnp.int32)
+    start = jnp.max(c.pos_view(), axis=(1, 2)) + 1
+    pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+    pos = jnp.where(jnp.arange(T)[None] < n_tok[:, None], pos, -1)
+    return k, v, pos, score, n_tok
+
+
+def _fill(c, n_tok, T, seed):
+    return _seq_append(c, *_chunk(c, n_tok, T, seed))
+
+
+def _state_two_rows():
+    return pc.init_layer_cache(2, 4, 4, 2, 8, jnp.float32,
+                               track_stats=True), [10, 7], 10
+
+
+def _state_mixed_n_tok():
+    # heads at offsets 0..3 and full; chunk lengths 0, 1, page-1, page, T
+    c = pc.init_layer_cache(5, 7, 4, 2, 8, jnp.float32, track_stats=True)
+    c = _fill(c, [0, 1, 2, 3, 4], 4, 1)
+    return c, [0, 1, 3, 4, 10], 10
+
+
+def _state_parked_after_release():
+    c = pc.init_layer_cache(3, 6, 4, 2, 8, jnp.float32, track_stats=True)
+    c = _fill(c, [6, 9, 3], 9, 1)
+    c = pc.release_rows(c, jnp.array([False, True, False]))
+    return c, [9, 9, 2], 9
+
+
+def _state_adopted_prefix():
+    c = pc.init_layer_cache(3, 6, 4, 2, 8, jnp.float32, track_stats=True)
+    c = _fill(c, [10, 3, 5], 10, 1)
+    c = pc.release_rows(c, jnp.array([False, True, False]))
+    c = pc.adopt_prefix(c, jnp.array([-1, 0, -1]), jnp.array([0, 2, 0]),
+                        enable=jnp.array([False, True, False]))
+    return c, [3, 7, 8], 8
+
+
+def _state_int8():
+    c = pc.init_layer_cache(3, 5, 4, 2, 8, "int8", track_stats=True)
+    c = _fill(c, [3, 5, 0], 6, 1)
+    return c, [9, 4, 8], 9
+
+
+def _state_emptied_pages():
+    # row 0: its partly written head page emptied (stays: it is current);
+    # row 1: a full non-current page emptied (reclaimed at the first
+    # rollover); row 2: its full head page emptied (reclaimed as it rolls)
+    B, P, page = 3, 6, 4
+    c = pc.init_layer_cache(B, P, page, 2, 8, jnp.float32, track_stats=True)
+    c = _fill(c, [6, 10, 8], 10, 1)
+    mask = np.zeros((B, P, page), bool)
+    mask[0, 1, :2] = True
+    mask[1, 1, :] = True
+    mask[2, 1, :] = True
+    c = pc.evict_token_mask(c, jnp.asarray(mask))
+    return c, [5, 3, 6], 6
+
+
+def _state_same_t_rollover():
+    # every row rolls at the same tokens, onto a free list with holes
+    c = pc.init_layer_cache(4, 6, 4, 2, 8, jnp.float32, track_stats=True)
+    c = _fill(c, [6, 6, 6, 6], 6, 1)
+    c = pc.release_rows(c, jnp.array([False, True, False, False]))
+    c = _fill(c, [2, 2, 0, 0], 2, 2)
+    return c, [10, 10, 10, 10], 10
+
+
+def _state_decode_t1():
+    c = pc.init_layer_cache(4, 5, 4, 2, 8, jnp.float32, track_stats=True)
+    c = _fill(c, [3, 4, 1, 8], 8, 1)
+    c = pc.release_rows(c, jnp.array([False, False, True, False]))
+    return c, [1, 1, 1, 0], 1
+
+
+@pytest.mark.parametrize("state", [
+    _state_two_rows, _state_mixed_n_tok, _state_parked_after_release,
+    _state_adopted_prefix, _state_int8, _state_emptied_pages,
+    _state_same_t_rollover, _state_decode_t1])
+def test_append_chunk_matches_sequential_writes(state):
     """append_chunk (the unified-step write path) must produce exactly the
     cache a per-token write_token + rollover sequence produces — pages
-    filled in order, fresh pages from the free list at each boundary."""
-    B, P, page, T = 2, 4, 4, 10
-    c = _cache(B=B, P=P, page=page)
-    rng = jax.random.PRNGKey(0)
-    k = jax.random.normal(rng, (B, T, 2, 8))
-    v = jax.random.normal(jax.random.fold_in(rng, 1), (B, T, 2, 8))
-    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    n_tok = jnp.array([T, 7])
-    pos = jnp.where(jnp.arange(T)[None] < n_tok[:, None], pos, -1)
-    score = jnp.zeros((B, T))
-    out = pc.append_chunk(c, k, v, pos, score, n_tok)
-
-    seq = c
-    for t in range(T):
-        act = jnp.arange(T)[t] < n_tok
-        seq = pc.chunk_rollover(seq, act & (seq.cur_off >= seq.page_size))
-        seq = pc.write_token(seq, k[:, t], v[:, t], pos[:, t], score[:, t],
-                             active=act)
-    for name in ("k", "v", "pos", "score", "block_table", "ref_count",
-                 "cur_page", "cur_off"):
-        np.testing.assert_array_equal(np.asarray(getattr(out, name)),
-                                      np.asarray(getattr(seq, name)),
-                                      err_msg=name)
-    np.testing.assert_array_equal(np.asarray(out.total_valid()), [T, 7])
+    filled in order, fresh pages from the free list at each boundary, in
+    the order the sequence takes them — field by field, under jit, and
+    through the one-scatter path (its fallback counter stays 0)."""
+    c, n_tok, T = state()
+    k, v, pos, score, n_tok = _chunk(c, n_tok, T, 7)
+    out = jax.jit(pc.append_chunk)(c, k, v, pos, score, n_tok)
+    seq = _seq_append(c, k, v, pos, score, n_tok)
+    for name in pc.PagedLayerCache._fields:
+        a, b = getattr(out, name), getattr(seq, name)
+        assert (a is None) == (b is None), name
+        if a is not None:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+    assert int(out.stats[devstats.CHUNK_APPEND_FALLBACKS]) == 0
+    np.testing.assert_array_equal(np.asarray(out.total_valid()),
+                                  np.asarray(c.total_valid() + n_tok))
 
 
 def test_append_chunk_allocates_from_shared_free_list():
@@ -224,9 +316,11 @@ def test_release_rows_returns_pages_and_rearms_head():
 def test_append_chunk_force_evicts_when_pool_dry():
     """Unstructured token policies can pin every logical slot with
     one-token survivor pages; the chunk rollover must then force-evict the
-    fewest-token page rather than silently drop the incoming K/V."""
+    fewest-token page rather than silently drop the incoming K/V. Only the
+    per-token fallback force-evicts, and its counter says it ran."""
     B, P, page = 1, 3, 4
-    c = _cache(B=B, P=P, page=page)                 # pool == 3 pages
+    c = pc.init_layer_cache(B, P, page, 2, 8, jnp.float32,
+                            track_stats=True)       # pool == 3 pages
     T = 3 * page
     pos = jnp.arange(T, dtype=jnp.int32)[None]
     c = pc.append_chunk(c, jnp.ones((B, T, 2, 8)), jnp.ones((B, T, 2, 8)),
@@ -251,6 +345,22 @@ def test_append_chunk_force_evicts_when_pool_dry():
     np.testing.assert_array_equal(np.bincount(mapped, minlength=c.pool_pages),
                                   ref)
     assert (np.asarray(c.pos)[ref == 0] == -1).all()
+    st = np.asarray(c.stats)
+    assert st[devstats.FORCED_EVICTIONS] == 1
+    assert st[devstats.CHUNK_APPEND_FALLBACKS] == 1
+    # structured PagedEviction never needs the fallback: the chunk-prefill
+    # setting (decode churned past budget, then a chunk with a short row)
+    from tests.test_chunked_prefill import _churned_cache
+    c, steps = _churned_cache(page=8)
+    c = c._replace(stats=devstats.zeros())
+    B, T = c.batch, 16
+    n_tok = jnp.array([T, T - 5])
+    pos = steps + jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    pos = jnp.where(jnp.arange(T)[None] < n_tok[:, None], pos, -1)
+    kv = jnp.ones((B, T) + c.k.shape[2:])
+    c = pc.append_chunk(c, kv, kv, pos, jnp.zeros((B, T)), n_tok)
+    assert int(c.stats[devstats.CHUNK_APPEND_FALLBACKS]) == 0
+    assert int(c.stats[devstats.TOKENS_WRITTEN]) == 2 * T - 5
 
 
 def test_evict_pages_mask_multi_victim():

@@ -89,6 +89,32 @@ def test_paged_eviction_evicts_lowest_scoring_page():
     assert {4, 5, 6, 7}.issubset(live)
 
 
+@pytest.mark.parametrize("hook", ["post_write", "chunk_prefill_evict"])
+def test_paged_eviction_tie_goes_to_older_page(hook):
+    """Pages whose mean scores are equal leave oldest first, whatever slots
+    they sit in: slot 0 holds positions 8..11 and slot 1 the older 0..3,
+    both scoring 1.0; 12 live tokens over a budget of 8 evict one page."""
+    pol = get_policy("paged_eviction")
+    cfg = _ccfg("paged_eviction", page=4, budget=8)
+    c = init_layer_cache(1, 4, 4, 1, 4, jnp.float32)
+    first = jnp.array([8, 0, 12, -1])                     # per physical page
+    pos = jnp.where(first[:, None] >= 0, first[:, None] + jnp.arange(4), -1)
+    score = jnp.where(pos >= 0, jnp.array([1.0, 1.0, 5.0, 0.0])[:, None],
+                      -jnp.inf)
+    c = c._replace(pos=pos.astype(jnp.int32), score=score,
+                   block_table=jnp.array([[0, 1, 2, -1]], jnp.int32),
+                   ref_count=jnp.array([1, 1, 1, 0], jnp.int32),
+                   cur_page=jnp.array([2], jnp.int32),
+                   cur_off=jnp.array([4], jnp.int32))
+    if hook == "post_write":
+        c = pol.post_write(c, cfg).cache
+    else:
+        c = pol.chunk_prefill_evict(c, cfg)
+    live = set(np.asarray(c.pos_view()).ravel().tolist()) - {-1}
+    assert live.isdisjoint(range(4))
+    assert set(range(8, 16)) <= live
+
+
 # ---------------------------------------------------------------------------
 # baselines
 # ---------------------------------------------------------------------------
