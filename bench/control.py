@@ -12,6 +12,7 @@ one process, so set-up and compilation are paid once.
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import pathlib
 import sys
@@ -71,7 +72,8 @@ def main(argv=None) -> int:
                        control_correct=bool(ok and keep["rest_ok"]),
                        control_s=time.perf_counter() - t0)
         print(json.dumps(row), flush=True)
-        del keep
+        del keep, res
+        gc.collect()        # the run's engine holds cycles: free its weights
     return 0
 
 

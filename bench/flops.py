@@ -9,22 +9,34 @@ them) plus its own page up to itself.
 """
 from __future__ import annotations
 
+from bench import arch
 
-def head_dim(cfg: dict) -> int:
-    return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
+# the family's per-layer counts (``bench/arch/<model_type>.py``)
 
 
 def matmul_params(cfg: dict) -> int:
     """Weights multiplied per token, every layer, without the LM head."""
-    D, F = cfg["hidden_size"], cfg["intermediate_size"]
-    H, KV, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], head_dim(cfg)
-    per_layer = D * (H + 2 * KV) * hd + H * hd * D + 3 * D * F
-    return per_layer * cfg["num_hidden_layers"]
+    return arch.of(cfg).matmul_params(cfg)
 
 
 def lm_head_flops(cfg: dict) -> int:
     """One row's logits (the program computes them at one position per row)."""
-    return 2 * cfg["hidden_size"] * cfg["vocab_size"]
+    return arch.of(cfg).lm_head_flops(cfg)
+
+
+def attn_flops(cfg: dict, keys: int) -> int:
+    """Attention over ``keys`` (query, key) pairs, all heads, one layer."""
+    return arch.of(cfg).attn_flops(cfg, keys)
+
+
+def kv_bytes(cfg: dict, tokens: int, itemsize: int = 2) -> int:
+    """The cached state of ``tokens`` tokens, one layer."""
+    return arch.of(cfg).kv_bytes(cfg, tokens, itemsize)
+
+
+def qo_bytes(cfg: dict, queries: int, itemsize: int = 2) -> int:
+    """Queries read and outputs written, one layer."""
+    return arch.of(cfg).qo_bytes(cfg, queries, itemsize)
 
 
 def visible_keys(p: int, budget: int, page: int) -> int:
@@ -37,21 +49,6 @@ def chunk_visible_keys(start: int, n: int, budget: int, page: int) -> int:
     (chunks start on page boundaries: the kept pages, then causal)."""
     kept = page * min(start // page, budget // page)
     return n * kept + n * (n + 1) // 2
-
-
-def attn_flops(cfg: dict, keys: int) -> int:
-    """QK^T and PV over ``keys`` (query, key) pairs, all heads, one layer."""
-    return 4 * cfg["num_attention_heads"] * head_dim(cfg) * keys
-
-
-def kv_bytes(cfg: dict, tokens: int, itemsize: int = 2) -> int:
-    """K and V of ``tokens`` cached tokens, one layer."""
-    return 2 * tokens * cfg["num_key_value_heads"] * head_dim(cfg) * itemsize
-
-
-def qo_bytes(cfg: dict, queries: int, itemsize: int = 2) -> int:
-    """Queries read and outputs written, one layer."""
-    return 2 * queries * cfg["num_attention_heads"] * head_dim(cfg) * itemsize
 
 
 def decode_row_work(cfg: dict, p: int, budget: int, page: int):

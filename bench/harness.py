@@ -4,9 +4,11 @@ reference, and reduce the timings (and, when traced, the profiler trace).
 
 A cell is a ``workloads`` entry of ``BENCHMARK.json``; it names a
 configuration (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``). Per-layer metrics are readers in
-``bench/metrics/<metric>.py``. Adding a cell, a mix or a metric adds files
-and entries; nothing here changes.
+(``bench/traffic/<traffic>.json``). A configuration's architecture is the
+family file of its ``model_type`` (``bench/arch/<model_type>.py``), and
+its tensor-parallel degree its ``tp`` (absent: 1). Per-layer metrics are
+readers in ``bench/metrics/<metric>.py``. Adding a cell, a mix, a family or
+a metric adds files and entries; nothing here changes.
 """
 from __future__ import annotations
 
@@ -20,6 +22,8 @@ import time
 from dataclasses import dataclass, field
 
 import numpy as np
+
+from bench import arch
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -47,7 +51,7 @@ def load_cell(workload: str, root: pathlib.Path = ROOT) -> Cell:
     if w is None:
         raise SystemExit(f"unknown workload {workload!r}")
     bench = root / "bench"
-    return Cell(
+    cell = Cell(
         name=workload, chips=int(w["chips"]),
         config=json.loads((bench / "configs" / f"{w['config']}.json")
                           .read_text()),
@@ -56,22 +60,26 @@ def load_cell(workload: str, root: pathlib.Path = ROOT) -> Cell:
         check=json.loads((bench / "checks" / f"{workload}.json").read_text()),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, workload)],
         per_layer=[m for m in spec["per_layer"] if _applies(m, workload)])
+    arch.load(cell.config["config"]["model_type"], bench)
+    tp = tensor_parallel(cell.config)
+    if tp > cell.chips:
+        raise SystemExit(f"{workload}: tp={tp} exceeds the cell's "
+                         f"{cell.chips} chips")
+    kv = model_config(cell.config).num_kv_heads
+    if kv % tp:
+        raise SystemExit(f"{workload}: tp={tp} does not divide the "
+                         f"{kv} KV heads")
+    return cell
 
 
 def model_config(c: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig
+    """The program's ModelConfig for a configuration file (its family's)."""
+    return arch.of(c["config"]).program_config(c)
 
-    h = c["config"]
-    return ModelConfig(
-        name=c["name"], arch_type="dense", source=c["source"],
-        num_layers=h["num_hidden_layers"], d_model=h["hidden_size"],
-        num_heads=h["num_attention_heads"],
-        num_kv_heads=h["num_key_value_heads"],
-        head_dim=h.get("head_dim") or 0, d_ff=h["intermediate_size"],
-        vocab_size=h["vocab_size"], qkv_bias=h["model_type"] == "qwen2",
-        rope_theta=float(h["rope_theta"]), norm="rmsnorm", act=h["hidden_act"],
-        tie_embeddings=bool(h["tie_word_embeddings"]), dtype=h["torch_dtype"])
+
+def tensor_parallel(c: dict) -> int:
+    """A configuration file's tensor-parallel degree."""
+    return int(c.get("tp", 1))
 
 
 def load_reader(name: str, root: pathlib.Path = ROOT):
@@ -102,7 +110,7 @@ class Run:
     cell: Cell
     model: dict              # the configuration's published sizes
     cache: dict
-    chips: int
+    chips: int               # the chips the step runs on: its tp
     peaks: dict
     steps: list = field(default_factory=list)
     trace: object = None
@@ -197,7 +205,11 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     c, mix = cell.config, cell.mix
     cc = c["cache"]
     mcfg = model_config(c)
-    params = make_params(mcfg, seed)
+    tp = tensor_parallel(c)
+    mesh = (jax.make_mesh((1, tp), ("data", "model"), devices=devices[:tp])
+            if tp > 1 else None)
+    params = make_params(mcfg, seed, getattr(arch.of(c["config"]), "STD",
+                                             None))
     reqs = generate(mix, seed, -float(mix.get("preroll_s", 0.0)),
                     seconds + DRAIN_S, mcfg.vocab_size)
     max_prompt = int(mix["prompt_len"]["max"])
@@ -209,7 +221,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                  sampling=SamplingParams(greedy=True), seed=0,
                  chunk_size=cc["chunk_size"],
                  token_budget=int(mix["token_budget"]),
-                 obs=ObsConfig(profiler_annotations=trace))
+                 obs=ObsConfig(profiler_annotations=trace), tp=tp, mesh=mesh)
     clock = _Clock()
     inflight: list = []
     served: list = []
@@ -279,7 +291,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     clock.window = (t_open, t_open + seconds if not closed else float("inf"))
     trace_dir = None
     if trace:       # the window's last seconds; stop_trace blocks, so it
-        tr_lo = seconds - float(mix["trace_seconds"])    # runs after close
+        tr_lo = seconds - float(mix["trace_seconds"])    # runs after the drain
     tracing = profiling = False
     due = [] if closed else [(q.due, q) for q in reqs]
     due_reqs = []
@@ -306,11 +318,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             tspan = jax.profiler.TraceAnnotation("bench.traced")
             tspan.__enter__()
             tracing = True
-        if profiling and now >= seconds:
-            if tracing:
-                tspan.__exit__(None, None, None)
-            jax.profiler.stop_trace()
-            profiling = False
+        if tracing and now >= seconds:
+            tspan.__exit__(None, None, None)
+            tracing = False
         if now >= seconds:
             if closed:
                 break
@@ -323,6 +333,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             nxt = due[i][0] if i < len(due) else seconds + DRAIN_S
             time.sleep(max(0.0, nxt - now))
     t_end = time.perf_counter()
+    if profiling:
+        jax.profiler.stop_trace()
     window_s = (t_end if closed else clock.window[1]) - t_open
     setup_s = t_open - t_process
 
@@ -405,12 +417,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     else:
         from bench import trace as trace_mod
         pk = peaks_mod.chip_peaks(devices[0].device_kind)
-        run_rec = Run(cell, c["config"], cc, len(devices), pk,
+        run_rec = Run(cell, c["config"], cc, tp, pk,
                       steps=record["steps"])
         files = list(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
         tr = trace_mod.load(str(files[0]))
         shutil.rmtree(trace_dir, ignore_errors=True)
-        tr.chips = tr.chips[:len(devices)]      # the cell's chips only
+        tr.chips = tr.chips[:tp]            # the chips the step runs on
         run_rec.trace = tr
         win = tr.span("bench.traced")
         run_rec.traced = (win[1], win[2])

@@ -2,34 +2,33 @@
 lower-precision control.
 
 The reference computes, in float32 at ``highest`` matmul precision, the
-logits of a decoder-only transformer with grouped-query attention (rotary
-positions, RMSNorm, SwiGLU, optional q/k/v biases) over each request's
-prompt and served tokens, with the keys each query may see decided by
-PagedEviction (paper Alg. 1-3) at the configured page size, budget and
-prefill chunk:
+logits of the served model over each request's prompt and served tokens,
+with the keys each query may see decided by PagedEviction (paper Alg.
+1-3) at the configured page size, budget and prefill chunk:
 
-- a token's importance is mean_h ||V_h|| / mean_h ||K_h||, a page's the
-  mean over its tokens;
+- a token's importance is its family's score (``bench/arch/<model_type>.py``
+  ``token_score``), a page's the mean over its tokens;
 - the prompt is processed in chunks; after each chunk, while more than
   budget/page full pages are kept, the lowest-scoring one goes;
 - each decoded token is written, attended, and when it fills its page and
   more than ``budget`` tokens are kept, the lowest-scoring full page goes.
 
 A page that goes after step s is still seen by the queries of step s.
-Everything here is written from that description: it imports nothing of
-the program and reads only the weights the benchmark drew and the tokens
-the program served. The control (``quant=True``) is the same computation
-with every matmul operand rounded to float8 e4m3 (per-tensor absmax
-scale), the precision step below the configuration's bfloat16.
+The layers are the family's (``hidden``, ``logits``); what is here is
+shared by every family. Everything is written from the description: it
+imports nothing of the program and reads only the weights the benchmark
+drew and the tokens the program served. The control (``quant=True``) is
+the same computation with every matmul operand rounded to float8 e4m3
+(per-tensor absmax scale), the precision step below the configuration's
+bfloat16.
 """
 from __future__ import annotations
-
-import functools
-import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import arch
 
 F8_MAX = 448.0
 BIG = 1 << 30
@@ -41,86 +40,17 @@ def fq(x):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(a, b, quant):
+def mm(a, b, quant):
+    """float32 matmul at ``highest`` precision; float8 operands if ``quant``."""
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     if quant:
         a, b = fq(a), fq(b)
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
-def _rms(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * \
-        scale.astype(jnp.float32)
-
-
-def _rope(x, pos, theta):
-    """x: (R, N, heads, hd); rotates pairs (2i, 2i+1) by pos / theta^(2i/hd)."""
-    hd = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = pos[..., None].astype(jnp.float32) * inv            # (R, N, hd/2)
-    c, s = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], -1).reshape(x.shape)
-
-
-@functools.partial(jax.jit, static_argnames=("H", "KV", "hd", "eps", "theta",
-                                             "quant"))
-def _qkv(x, lw, pos, *, H, KV, hd, eps, theta, quant):
-    R, N, _ = x.shape
-    a = lw["attn"]
-    h = _rms(x, lw["norm1"]["scale"], eps)
-    q, k, v = (_mm(h, a[w], quant) for w in ("wq", "wk", "wv"))
-    if "bq" in a:
-        q = q + a["bq"].astype(jnp.float32)
-        k = k + a["bk"].astype(jnp.float32)
-        v = v + a["bv"].astype(jnp.float32)
-    q = _rope(q.reshape(R, N, H, hd), pos, theta)
-    k = _rope(k.reshape(R, N, KV, hd), pos, theta)
-    v = v.reshape(R, N, KV, hd)
-    kn = jnp.mean(jnp.linalg.norm(k, axis=-1), -1)
-    vn = jnp.mean(jnp.linalg.norm(v, axis=-1), -1)
-    return q, k, v, vn / jnp.maximum(kn, 1e-6)
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "quant", "qblock"))
-def _attn_mlp(x, q, k, v, lw, st, evk, *, eps, quant, qblock):
-    R, N, H, hd = q.shape
-    KV = k.shape[2]
-    G = H // KV
-    kpos = jnp.arange(N)
-
-    def block(i):
-        qb = jax.lax.dynamic_slice_in_dim(q, i * qblock, qblock, 1)
-        sb = jax.lax.dynamic_slice_in_dim(st, i * qblock, qblock, 1)
-        qpos = i * qblock + jnp.arange(qblock)
-        qg = qb.reshape(R, qblock, KV, G, hd)
-        kk, vv = (fq(k), fq(v)) if quant else (k, v)
-        qg = fq(qg) if quant else qg
-        s = jnp.einsum("rqkgd,rskd->rkgqs", qg, kk,
-                       precision=jax.lax.Precision.HIGHEST) / math.sqrt(hd)
-        seen = (kpos[None, None, :] <= qpos[None, :, None]) & \
-            (evk[:, None, :] >= sb[:, :, None])               # (R, qb, N)
-        s = jnp.where(seen[:, None, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        p = fq(p) if quant else p
-        o = jnp.einsum("rkgqs,rskd->rqkgd", p, vv,
-                       precision=jax.lax.Precision.HIGHEST)
-        return o.reshape(R, qblock, H * hd)
-
-    o = jax.lax.map(block, jnp.arange(N // qblock))           # (nb, R, qb, .)
-    o = jnp.moveaxis(o, 0, 1).reshape(R, N, H * hd)
-    x = x + _mm(o, lw["attn"]["wo"], quant)
-    m = lw["mlp"]
-    h = _rms(x, lw["norm2"]["scale"], eps)
-    g = _mm(h, m["w_gate"], quant)
-    u = _mm(h, m["w_up"], quant)
-    return x + _mm(jax.nn.silu(g) * u, m["w_down"], quant)
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "quant"))
-def _logits(h, norm, head, probes, *, eps, quant):
-    """h: (R, n, D) -> best logit, argmax token, and each probe set's logit."""
-    z = _mm(_rms(h, norm, eps), head.T, quant)                # (R, n, V)
+@jax.jit
+def _pick(z, probes):
+    """z: (R, n, V) -> best logit, argmax token, and each probe set's logit."""
     picked = jnp.take_along_axis(z[None], probes[..., None], axis=-1)[..., 0]
     return jnp.max(z, -1), jnp.argmax(z, -1).astype(jnp.int32), picked
 
@@ -167,10 +97,7 @@ def run(params, cfg: dict, cache: dict, seqs, probes=(), *, quant=False):
     once, layer by layer. ``probes``: token sets shaped like the served
     tokens whose logits to report. Returns, per request, arrays over the
     served positions: best logit, argmax token, and each probe's logit."""
-    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    D = cfg["hidden_size"]
-    hd = cfg.get("head_dim") or D // H
-    eps, theta = float(cfg["rms_norm_eps"]), float(cfg["rope_theta"])
+    fam = arch.of(cfg)
     page, budget, chunk = cache["page_size"], cache["cache_budget"], \
         cache["chunk_size"]
     R = len(seqs)
@@ -182,12 +109,10 @@ def run(params, cfg: dict, cache: dict, seqs, probes=(), *, quant=False):
     for r, t in enumerate(toks):
         tok[r, :len(t)] = t
     pos = jnp.asarray(np.broadcast_to(np.arange(N, dtype=np.int32), (R, N)))
-    x = jnp.take(params["embed"], jnp.asarray(tok), axis=0).astype(jnp.float32)
-    stack = params["pattern"][0]
-    for layer in range(cfg["num_hidden_layers"]):
-        lw = jax.tree.map(lambda a: a[layer], stack)
-        q, k, v, ts = _qkv(x, lw, pos, H=H, KV=KV, hd=hd, eps=eps,
-                           theta=theta, quant=quant)
+
+    def visible(ts):
+        """One layer's token scores -> (step of each position, step after
+        which each position's page leaves)."""
         ts = np.asarray(jax.device_get(ts))
         st = np.zeros((R, N), np.int32)
         evk = np.full((R, N), -1, np.int32)
@@ -196,9 +121,9 @@ def run(params, cfg: dict, cache: dict, seqs, probes=(), *, quant=False):
                                 page=page, chunk=chunk)
             st[r, :len(t)] = s
             evk[r, :len(t)] = np.minimum(ev, BIG)[np.arange(len(t)) // page]
-        x = _attn_mlp(x, q, k, v, lw, jnp.asarray(st), jnp.asarray(evk),
-                      eps=eps, quant=quant, qblock=128)
-        del q, k, v
+        return jnp.asarray(st), jnp.asarray(evk)
+
+    x = fam.hidden(params, cfg, jnp.asarray(tok), pos, visible, quant=quant)
     idx = np.zeros((R, n_max), np.int32)
     pr = np.zeros((max(len(probes), 1), R, n_max), np.int32)
     for r, (p, s) in enumerate(seqs):
@@ -206,10 +131,9 @@ def run(params, cfg: dict, cache: dict, seqs, probes=(), *, quant=False):
         for i, ps in enumerate(probes):
             pr[i, r, :len(s)] = ps[r]
     h = jnp.take_along_axis(x, jnp.asarray(idx)[..., None], axis=1)
-    head = params["embed"] if cfg["tie_word_embeddings"] else params["lm_head"]
-    best, arg, picked = jax.device_get(_logits(
-        h, params["final_norm"]["scale"], head, jnp.asarray(pr), eps=eps,
-        quant=quant))
+    del x
+    best, arg, picked = jax.device_get(_pick(
+        fam.logits(params, cfg, h, quant=quant), jnp.asarray(pr)))
     out = []
     for r, (_, s) in enumerate(seqs):
         n = len(s)

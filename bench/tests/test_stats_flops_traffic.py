@@ -8,7 +8,7 @@ from bench.traffic.generate import generate
 
 QWEN = {"hidden_size": 2048, "intermediate_size": 11008,
         "num_attention_heads": 16, "num_key_value_heads": 2,
-        "num_hidden_layers": 36, "vocab_size": 151936}
+        "num_hidden_layers": 36, "vocab_size": 151936, "model_type": "qwen2"}
 
 
 @pytest.mark.parametrize("p", [0, 10, 50, 90, 95, 99, 100])
