@@ -20,10 +20,25 @@ on the architecture:
     matmul_params(cfg), lm_head_flops(cfg), attn_flops(cfg, keys),
     kv_bytes(cfg, tokens, itemsize), qo_bytes(cfg, queries, itemsize)
                             its work counts (``bench/flops.py``)
+    rehearse(params, cfg, x, pos, h, *, quant, call, layer)
+                            optional: one layer of ``hidden`` and then
+                            ``logits``, on ShapeDtypeStructs with their
+                            shardings, each jitted part run through
+                            ``call(f, *args, **kw)`` (which compiles it and
+                            returns its outputs' shapes); ``layer(a)`` is one
+                            layer's shape of a stacked leaf. Without it
+                            ``bench/rehearse.py`` compiles no reference
     STD                     optional: standard deviations by leaf name that
                             ``bench/weights.py`` draws with
 
 Adding a family adds its file; nothing else changes.
+
+A model larger than one chip needs nothing more of a family: its weights
+arrive split over the chips as the program places them, seen on a mesh
+whose axes the compiler places (``reference.on_auto_axes``), so the plain
+layers above run where the weights lie, with no gather. What they need per
+chip, at ``reference.ROWS`` requests per pass, the rehearsal shows before a
+chip is spent.
 """
 from __future__ import annotations
 

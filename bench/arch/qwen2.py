@@ -111,20 +111,36 @@ def _attn_mlp(x, q, k, v, lw, st, evk, *, eps, quant, qblock):
     return x + mm(jax.nn.silu(g) * u, m["w_down"], quant)
 
 
+QBLOCK = 128        # queries per block of the reference's attention
+
+
+def _layer_kw(cfg: dict) -> dict:
+    return dict(H=cfg["num_attention_heads"], KV=cfg["num_key_value_heads"],
+                hd=head_dim(cfg), eps=float(cfg["rms_norm_eps"]),
+                theta=float(cfg["rope_theta"]))
+
+
+def _call(f, *args, **kw):
+    return f(*args, **kw)
+
+
+def _layer(x, lw, pos, visible, kw, quant, call=_call):
+    """One layer: ``call(f, *args, **kw)`` runs each jitted part (the
+    rehearsal compiles it instead)."""
+    q, k, v, ts = call(_qkv, x, lw, pos, quant=quant, **kw)
+    st, evk = visible(ts)
+    return call(_attn_mlp, x, q, k, v, lw, st, evk, eps=kw["eps"],
+                quant=quant, qblock=QBLOCK)
+
+
 def hidden(params, cfg: dict, tok, pos, visible, *, quant=False):
     """The residual stream after the last layer, (R, N, D) float32."""
-    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    eps, theta = float(cfg["rms_norm_eps"]), float(cfg["rope_theta"])
+    kw = _layer_kw(cfg)
     x = jnp.take(params["embed"], tok, axis=0).astype(jnp.float32)
     stack = params["pattern"][0]
     for layer in range(cfg["num_hidden_layers"]):
         lw = jax.tree.map(lambda a: a[layer], stack)
-        q, k, v, ts = _qkv(x, lw, pos, H=H, KV=KV, hd=head_dim(cfg), eps=eps,
-                           theta=theta, quant=quant)
-        st, evk = visible(ts)
-        x = _attn_mlp(x, q, k, v, lw, st, evk, eps=eps, quant=quant,
-                      qblock=128)
-        del q, k, v
+        x = _layer(x, lw, pos, visible, kw, quant)
     return x
 
 
@@ -133,11 +149,18 @@ def _logits(h, norm, head, *, eps, quant):
     return mm(_rms(h, norm, eps), head.T, quant)
 
 
-def logits(params, cfg: dict, h, *, quant=False):
+def logits(params, cfg: dict, h, *, quant=False, call=_call):
     """(R, n, D) final hidden states -> (R, n, V) logits."""
     head = params["embed"] if cfg["tie_word_embeddings"] else params["lm_head"]
-    return _logits(h, params["final_norm"]["scale"], head,
-                   eps=float(cfg["rms_norm_eps"]), quant=quant)
+    return call(_logits, h, params["final_norm"]["scale"], head,
+                eps=float(cfg["rms_norm_eps"]), quant=quant)
+
+
+def rehearse(params, cfg: dict, x, pos, h, *, quant, call, layer):
+    """``hidden``'s layer and ``logits`` run through ``call`` on shapes."""
+    lw = jax.tree.map(layer, params["pattern"][0])
+    _layer(x, lw, pos, lambda ts: (pos, pos), _layer_kw(cfg), quant, call)
+    return logits(params, cfg, h, quant=quant, call=call)
 
 
 # ---- work counts -----------------------------------------------------------

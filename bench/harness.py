@@ -9,6 +9,14 @@ family file of its ``model_type`` (``bench/arch/<model_type>.py``), and
 its tensor-parallel degree its ``tp`` (absent: 1). Per-layer metrics are
 readers in ``bench/metrics/<metric>.py``. Adding a cell, a mix, a family or
 a metric adds files and entries; nothing here changes.
+
+A model larger than one chip: at ``tp`` > 1 the weights are drawn straight
+into the program's tensor-parallel placement over the cell's first ``tp``
+chips (``bench/weights.py``), so no chip ever holds more than its share,
+and the engine takes them where they lie. The reference then runs on those
+same split weights, ``reference.ROWS`` requests per pass, and never gathers
+a whole matrix onto one chip; ``bench/rehearse.py`` prints what the draw
+and each reference program need per chip.
 """
 from __future__ import annotations
 
@@ -209,7 +217,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     mesh = (jax.make_mesh((1, tp), ("data", "model"), devices=devices[:tp])
             if tp > 1 else None)
     params = make_params(mcfg, seed, getattr(arch.of(c["config"]), "STD",
-                                             None))
+                                             None), mesh=mesh)
     reqs = generate(mix, seed, -float(mix.get("preroll_s", 0.0)),
                     seconds + DRAIN_S, mcfg.vocab_size)
     max_prompt = int(mix["prompt_len"]["max"])
@@ -385,6 +393,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     gaps = (reference.served_gaps(params, c["config"], cc, seqs)
             if seqs else [])
     ref_s = time.perf_counter() - t_ref
+    ref_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                   for d in devices)
     evict_ok = {"window": evicted_window > 0,
                 "never": evicted_setup + evicted_window == 0}[mix["evicts"]]
     rest_ok = (n_checked >= chk["min_tokens"] and forced == 0 and evict_ok
@@ -445,5 +455,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     result["device"] = devs
     result["checks"] = checks
     log(json.dumps({"reference": {"requests": len(seqs), "tokens": n_checked,
-                                  "seconds": ref_s}}))
+                                  "seconds": ref_s,
+                                  "peak_bytes_after": int(ref_peak)}}))
     return result

@@ -88,6 +88,24 @@ def evict_steps(tok_scores: np.ndarray, L: int, *, budget: int, page: int,
     return ev, st
 
 
+def on_auto_axes(params):
+    """The weights where they lie, on a mesh whose axes are left to the
+    compiler: a sharded weight stays split as the program placed it, and
+    the plain layers need no sharding annotations. A view: no copy."""
+    from jax.sharding import AxisType, Mesh, NamedSharding
+
+    def view(a):
+        s = a.sharding
+        if not isinstance(s, NamedSharding) or \
+                all(t == AxisType.Auto for t in s.mesh.axis_types):
+            return a
+        m = Mesh(s.mesh.devices, s.mesh.axis_names,
+                 axis_types=(AxisType.Auto,) * len(s.mesh.axis_names))
+        return jax.device_put(a, NamedSharding(m, s.spec))
+
+    return jax.tree.map(view, params)
+
+
 def _bucket(n: int, to: int = 256) -> int:
     return -(-n // to) * to
 
@@ -148,7 +166,7 @@ ROWS = 8        # requests per pass of the reference, so that it fits
 def served_gaps(params, cfg, cache, seqs):
     """Per request, the gaps (reference's best logit minus its logit of the
     served token) at every served position."""
-    out = []
+    params, out = on_auto_axes(params), []
     for i in range(0, len(seqs), ROWS):
         part = seqs[i:i + ROWS]
         res = run(params, cfg, cache, part, probes=[[s for _, s in part]])
@@ -159,7 +177,7 @@ def served_gaps(params, cfg, cache, seqs):
 def control_gaps(params, cfg, cache, seqs):
     """The control in the program's place: per request, the reference's gap
     of the token the float8 computation puts first, at the same positions."""
-    out = []
+    params, out = on_auto_axes(params), []
     for i in range(0, len(seqs), ROWS):
         part = seqs[i:i + ROWS]
         ctl = run(params, cfg, cache, part, quant=True)

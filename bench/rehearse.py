@@ -1,10 +1,19 @@
-"""Compile rehearsal: build a cell's two step programs (T=chunk and T=1)
-for a described TPU v5e (at the configuration's ``tp``, over that many of
-a described 2x2's chips), with no chip attached, and print what the
-compiler says each one needs per device (``memory_analysis()``) and how
-many Pallas kernels it holds. Nothing runs.
+"""Compile rehearsal: build, for a described TPU v5e (at the
+configuration's ``tp``, over that many of a described 2x2's chips) and
+with no chip attached, what a run of a cell puts on the chips: the two
+step programs (T=chunk and T=1), the draw of the weights, and the
+reference's programs (served and control: one layer's, and the logits) at
+the reference's rows per pass and the mix's longest prompt plus output,
+bucketed as ``reference.run`` buckets them. Prints what the compiler says
+each one needs per device (``memory_analysis()``), how many Pallas kernels
+a step holds, and how many all-gathers the others hold. Nothing runs.
 
-    JAX_PLATFORMS=cpu python3 bench/rehearse.py --workload <name>
+    JAX_PLATFORMS=cpu python3 bench/rehearse.py --workload <name> \
+        [--root <checkout>]
+
+``--root``: the checkout whose ``BENCHMARK.json`` and ``bench/`` files
+define the cell (default: this one). A request's served tokens are taken
+at the mix's cap, which a closed loop's window may never reach.
 """
 from __future__ import annotations
 
@@ -55,6 +64,7 @@ def _described(eng_args, tp, mesh):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
+    ap.add_argument("--root", type=pathlib.Path, default=ROOT)
     args = ap.parse_args(argv)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -66,14 +76,14 @@ def main(argv=None) -> int:
         SingleDeviceSharding
 
     jax.config.update("jax_enable_compilation_cache", False)
-    from bench import harness
+    from bench import arch, harness, reference, weights
     from repro.configs.base import CacheConfig
     from repro.kernels import block_score, flash_prefill, paged_attention
     from repro.models.transformer import init_model
     from repro.serving import SamplingParams
     from repro.serving import engine as engine_mod
 
-    cell = harness.load_cell(args.workload)
+    cell = harness.load_cell(args.workload, args.root.resolve())
     for mod in (block_score, flash_prefill, paged_attention):
         mod.interpret_mode = lambda interpret=None: False
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -105,7 +115,15 @@ def main(argv=None) -> int:
     else:
         put_params, put_cache = eng.params, eng.cache
     B = int(mix["max_batch"])
-    out = {}
+
+    def report(name, comp, **extra):
+        ma = comp.memory_analysis()
+        row = {"argument_bytes": ma.argument_size_in_bytes,
+               "output_bytes": ma.output_size_in_bytes,
+               "alias_bytes": ma.alias_size_in_bytes,
+               "temp_bytes": ma.temp_size_in_bytes, **extra}
+        print(json.dumps({args.workload: {name: row}}), flush=True)
+
     for T, step in ((cc["chunk_size"], eng._step_mixed), (1, eng._step_decode)):
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=dev)
         b = lambda: jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=dev)
@@ -114,16 +132,70 @@ def main(argv=None) -> int:
         comp = step.lower(
             put_params, i32(B, T), i32(B), b(), b(), b(), i32(B), i32(B),
             put_cache, key).compile()
-        ma = comp.memory_analysis()
-        out[f"T={T}"] = {
-            "compile_s": round(time.perf_counter() - t0, 1),
-            "argument_bytes": ma.argument_size_in_bytes,
-            "output_bytes": ma.output_size_in_bytes,
-            "alias_bytes": ma.alias_size_in_bytes,
-            "temp_bytes": ma.temp_size_in_bytes,
-            "tpu_custom_calls": comp.as_text().count("tpu_custom_call")}
-        print(json.dumps({args.workload: {f"T={T}": out[f"T={T}"]}}), flush=True)
+        report(f"T={T}", comp,
+               compile_s=round(time.perf_counter() - t0, 1),
+               tpu_custom_calls=comp.as_text().count("tpu_custom_call"))
+
+    # the draw, placed as harness.run places it
+    std = getattr(arch.of(cell.config["config"]), "STD", None)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev)
+    comp = weights.drawer(mcfg, std, mesh if tp > 1 else None) \
+        .lower(key).compile()
+    drawn = _out_shapes(comp)
+    report("draw", comp, all_gathers=_gathers(comp))
+
+    # the reference on the drawn weights, at its rows per pass and the
+    # longest prompt plus output, bucketed as reference.run buckets them
+    cfg = cell.config["config"]
+    fam = arch.of(cfg)
+    if not hasattr(fam, "rehearse"):
+        print(json.dumps({args.workload: {"reference": "family has no "
+                                          "rehearse hook"}}), flush=True)
+        return 0
+    rows = min(reference.ROWS, int(cell.check["requests"]))
+    N = reference._bucket(int(mix["prompt_len"]["max"]) + max_new - 1)
+    n = reference._bucket(max_new, 128)
+    D = cfg["hidden_size"]
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=dev)
+    pos = jax.ShapeDtypeStruct((rows, N), jnp.int32, sharding=dev)
+    for label, quant in (("reference", False), ("control", True)):
+        def call(f, *a, **kw):
+            comp = f.lower(*a, **kw).compile()
+            report(f"{label}.{f.__name__.lstrip('_')}", comp, rows=rows,
+                   positions=N, served_positions=n,
+                   all_gathers=_gathers(comp))
+            return _out_shapes(comp)
+
+        z = fam.rehearse(drawn, cfg, f32(rows, N, D), pos, f32(rows, n, D),
+                         quant=quant, call=call, layer=_layer_shape)
+        probes = jax.ShapeDtypeStruct((1, rows, n), jnp.int32, sharding=dev)
+        call(reference._pick, z, probes)
     return 0
+
+
+def _layer_shape(a):
+    """The shape, with its sharding, of one layer ``a[i]`` of a stacked
+    leaf given as a ShapeDtypeStruct."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    s = a.sharding
+    if isinstance(s, NamedSharding):
+        s = NamedSharding(s.mesh, PartitionSpec(*s.spec[1:]))
+    return jax.ShapeDtypeStruct(a.shape[1:], a.dtype, sharding=s)
+
+
+def _out_shapes(compiled):
+    """A compiled program's outputs as ShapeDtypeStructs with the shardings
+    the compiler chose: the next program's arguments."""
+    import jax
+
+    return jax.tree.map(lambda o: jax.ShapeDtypeStruct(
+        o.shape, o.dtype, sharding=o.sharding), compiled.out_info)
+
+
+def _gathers(comp) -> int:
+    return comp.as_text().count(" all-gather(")
 
 
 if __name__ == "__main__":

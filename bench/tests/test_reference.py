@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from bench import harness, reference
-from bench.tests.tiny import tiny_cell
+from bench.tests.tiny import on_cpu_devices, tiny_cell
 
 
 def test_evict_steps_prompt_chunks_then_decode():
@@ -48,3 +48,34 @@ def test_reference_equals_a_float32_program_exactly():
     assert res["checks"]["tokens_checked"][0] >= 100
     assert gap == pytest.approx(0.0, abs=1e-5)
     assert res["correct"]
+
+
+AUTO_VIEW = """
+import jax
+from jax.sharding import AxisType
+from bench import arch, harness, reference
+from bench.tests.tiny import on_cpu_devices, tiny_cell
+from bench.weights import make_params
+
+c = tiny_cell().config
+mesh = jax.make_mesh((1, 4), ("data", "model"), devices=jax.devices()[:4])
+drawn = make_params(harness.model_config(c), 2**36 + 3,
+                    arch.of(c["config"]).STD, mesh=mesh)
+seen = reference.on_auto_axes(drawn)
+pairs = list(zip(jax.tree.leaves(drawn), jax.tree.leaves(seen), strict=True))
+assert any(a.sharding.mesh.axis_types != (AxisType.Auto,) * 2
+           for a, _ in pairs)
+for a, b in pairs:
+    assert set(b.sharding.mesh.axis_types) == {AxisType.Auto}
+    assert b.sharding.spec == a.sharding.spec
+    ptr = lambda x: [(s.device, s.data.unsafe_buffer_pointer())
+                     for s in x.addressable_shards]
+    assert ptr(a) == ptr(b), a.shape
+print("ok")
+"""
+
+
+def test_auto_axes_view_shares_the_weights_buffers():
+    """The reference sees split weights on an ``Auto``-axes mesh without a
+    copy: every shard of the view is the drawn shard's own buffer."""
+    assert on_cpu_devices(AUTO_VIEW, timeout=300).strip().endswith("ok")

@@ -41,3 +41,22 @@ def tiny_cell(loop="closed", dtype="bfloat16", limits=None):
     metrics = [{"name": n, "unit": "u"} for n in
                ("ttft_p90_s", "itl_p95_ms", "output_tok_s", "setup_s")]
     return harness.Cell("tiny", 1, cfg, mix, check, metrics, [])
+
+
+def on_cpu_devices(code: str, n: int = 4, timeout: float = 300) -> str:
+    """Run ``code`` in a fresh Python with ``n`` CPU devices (the flag has
+    to be set before jax is first imported) and this checkout on its path;
+    fails the calling test on a non-zero exit. Returns standard output."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parents[2]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={n}",
+               PYTHONPATH=os.pathsep.join([str(root), str(root / "src")]))
+    p = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                       capture_output=True, text=True, timeout=timeout)
+    assert p.returncode == 0, p.stderr[-4000:]
+    return p.stdout

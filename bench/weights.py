@@ -5,6 +5,12 @@ The tree's structure and shapes are the program's interface (taken from
 ``init_model`` by ``jax.eval_shape``: no values); every value is drawn
 here, from the seed, so the reference never reads weights the program made.
 A family may draw some leaves with other deviations (its ``STD``).
+
+Given a tensor-parallel mesh, the draw's outputs are placed as the program
+places its parameters (``rules.tp_param_shardings``), so each chip draws
+only its own shard of a split leaf and a model larger than one chip is
+never whole on any chip. The random bits are partitionable, so a leaf's
+values do not depend on the mesh.
 """
 from __future__ import annotations
 
@@ -24,9 +30,10 @@ def _leaf_name(path) -> str:
     return str(getattr(path[-1], "key", path[-1]))
 
 
-def make_params(model_cfg, seed: int, std: dict | None = None):
-    """Parameters of ``model_cfg`` (a repro ModelConfig) for ``seed``;
-    ``std``: deviations by leaf name over ``STD``."""
+def drawer(model_cfg, std: dict | None = None, mesh=None):
+    """The jitted draw of ``model_cfg``'s parameters (a repro ModelConfig)
+    from a key; ``std``: deviations by leaf name over ``STD``; ``mesh``: a
+    (1, tp) mesh whose TP placement the outputs take (None: one device)."""
     from repro.models.transformer import init_model
 
     shapes = jax.eval_shape(lambda k: init_model(k, model_cfg),
@@ -50,4 +57,12 @@ def make_params(model_cfg, seed: int, std: dict | None = None):
             out.append(x.astype(sds.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    return jax.jit(build)(seed_key(seed))
+    if mesh is None:
+        return jax.jit(build)
+    from repro.sharding import rules
+    return jax.jit(build, out_shardings=rules.tp_param_shardings(mesh, shapes))
+
+
+def make_params(model_cfg, seed: int, std: dict | None = None, mesh=None):
+    """Parameters of ``model_cfg`` for ``seed`` (see ``drawer``)."""
+    return drawer(model_cfg, std, mesh)(seed_key(seed))
