@@ -105,6 +105,9 @@ def serve(cfg, params, *, prompts, new_tokens: int, max_batch: int,
     say(f"pages_evicted={s.pages_evicted} tokens_evicted={s.tokens_evicted}"
         f" forced_evictions={s.forced_evictions}"
         f" chunk_append_fallbacks={s.chunk_append_fallbacks}")
+    snap = eng.metrics_snapshot()
+    say("decode walk:", *(f"{name}={snap[name]['value']}" for name in (
+        "kernel.decode.pages_per_block", "kernel.decode.grid_steps_per_layer")))
     check(len(done) == len(prompts), "not every request finished")
     check(all(r.num_generated == new_tokens for r in done),
           "a request stopped short of its token count")

@@ -55,9 +55,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import interpret_mode
-from repro.kernels.paged_attention import _pool_index, unit_rows
+from repro.kernels.paged_attention import unit_rows
 
 NEG_INF = -1e30
+
+
+def _pool_index(bt_ref, b, p):
+    """Physical page id for (request b, logical slot p); clamped so unmapped
+    slots DMA pool page 0 (masked in the kernel body)."""
+    return jnp.maximum(bt_ref[b, p], 0)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,

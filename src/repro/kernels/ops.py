@@ -45,9 +45,9 @@ def paged_attention(q, cache: PagedLayerCache, *, cur_pos, window: int = 0,
 
     q: (B, H, hd) current-token queries -> (B, H, hd), or
     ``(out, page_scores)`` with page_scores (B, P) when ``return_scores``
-    (the fused eviction-score epilogue, DESIGN.md §8). ``num_splits``
-    partitions the logical-page walk into independent split-K chunks
-    (long-context decode latency; DESIGN.md §8).
+    (the fused eviction-score epilogue, DESIGN.md §8). The kernel walks
+    the block table a block of pages per grid step; ``num_splits``
+    partitions that walk into independent split-K chunks (DESIGN.md §8).
     """
     B, H, hd = q.shape
     KV = cache.k.shape[2]

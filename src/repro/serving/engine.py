@@ -54,6 +54,7 @@ from repro.configs.base import CacheConfig, ModelConfig
 from repro.core import devstats
 from repro.core.paged_cache import lineage_snapshot
 from repro.core.policies import EvictionPolicy, get_policy
+from repro.kernels.paged_attention import decode_grid
 from repro.models.transformer import (
     ModelCache,
     collect_step_stats,
@@ -227,12 +228,32 @@ class Engine:
             free += reps * (n - max_batch)
         self._pool_pages_total = total
         self._free_pages_est = free
+        if self.use_pallas and self.obs.cfg.metrics:
+            self._gauge_decode_walk()
         self._probe_fn = jax.jit(intact_prefix_pages)
         # lineage ledger: one jitted gather of the FIRST attention layer's
         # pool view per step (block table, ref counts, per-page tokens /
         # base positions / policy scores)
         self._lineage_fn = (jax.jit(self._lineage_impl)
                             if self.obs.ledger is not None else None)
+
+    def _gauge_decode_walk(self) -> None:
+        """Record the decode kernel's walk at this engine's shapes (DESIGN.md
+        §8): ``kernel.decode.pages_per_block`` and
+        ``kernel.decode.grid_steps_per_layer``, for the longest block table
+        at ``max_batch`` rows and each chip's KV heads."""
+        kvs = [lc.kv for lc in list(self.cache.pattern) + list(self.cache.tail)
+               if lc.kv is not None]
+        if not kvs:
+            return
+        kv = max(kvs, key=lambda c: c.block_table.shape[-1])
+        _, page, n_kv, hd = kv.k.shape[-4:]
+        S, bps, ppb = decode_grid(kv.block_table.shape[-1], page, hd,
+                                  kv.k.dtype, self.decode_splits)
+        reg = self.obs.registry
+        reg.gauge("kernel.decode.pages_per_block").set(ppb)
+        reg.gauge("kernel.decode.grid_steps_per_layer").set(
+            self.max_batch * (n_kv // self.tp) * S * bps)
 
     def _init_tp(self, make_cache) -> None:
         """Build the tensor-parallel step: create the cache straight into

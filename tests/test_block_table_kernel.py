@@ -13,6 +13,7 @@ import pytest
 
 from repro.configs import CacheConfig
 from repro.core import decode_append, get_policy, init_layer_cache
+from repro.core.paged_cache import quantize_absmax
 from repro.kernels import ops, ref
 from repro.models.attention import paged_attention_ref as model_ref
 
@@ -46,7 +47,41 @@ def _driven_cache(policy, page, dtype, steps=None, B=2, KV=2, hd=64, seed=0):
     return cache, steps
 
 
-def _dense_reference(q, cache, cur):
+def _holed_cache(dtype, seed=0, B=2, P=73, page=16, KV=2, hd=64):
+    """A 73-slot table, not a whole number of decode blocks, with the holes
+    eviction leaves: row 0 maps slots 0-31 but for holes at 3 and 12 (mid
+    block), leaves 32-63 unmapped (a whole block at 8, 16 or 32 pages per
+    block) and maps 64-70; row 1 is short (slots 0-30). Some tokens of row
+    0's mapped pages are evicted (pos -1). Every pool page no table maps,
+    as evicted pages are once freed or handed to another row, holds tokens
+    whose positions pass both rows' masks, so a leak through an unmapped
+    slot would show. Same contract as :func:`_driven_cache`."""
+    rng = np.random.RandomState(seed)
+    N = B * P
+    perm = rng.permutation(N)
+    bt = np.full((B, P), -1, np.int32)
+    row0 = [s for s in range(71) if s not in (3, 12) and not 32 <= s < 64]
+    bt[0, row0] = perm[:len(row0)]
+    bt[1, :31] = perm[len(row0):len(row0) + 31]
+    pos = np.tile(np.arange(page, dtype=np.int32), (N, 1))
+    for b, s in zip(*np.nonzero(bt >= 0)):
+        pos[bt[b, s]] = s * page + np.arange(page)
+    pos[bt[0, 5], 3:7] = -1
+    k = jnp.asarray(rng.randn(N, page, KV, hd), jnp.float32)
+    v = jnp.asarray(rng.randn(N, page, KV, hd), jnp.float32)
+    quant = dtype == "int8"
+    cache = init_layer_cache(B, P, page, KV, hd,
+                             "int8" if quant else jnp.float32)
+    if quant:
+        (k, ks), (v, vs) = quantize_absmax(k), quantize_absmax(v)
+        cache = cache._replace(k_scale=ks, v_scale=vs)
+    cache = cache._replace(
+        k=k, v=v, pos=jnp.asarray(pos), block_table=jnp.asarray(bt),
+        ref_count=jnp.zeros((N,), jnp.int32).at[bt[bt >= 0]].set(1))
+    return cache, 71 * page
+
+
+def _dense_reference(q, cache, cur, window=0):
     """Dense oracle from kernels/ref.py on the gathered (dequantized) view."""
     B, H, hd = q.shape
     KV = cache.k.shape[2]
@@ -54,7 +89,8 @@ def _dense_reference(q, cache, cur):
     kg = jnp.moveaxis(cache.k_view(), 3, 1)        # (B, KV, P, page, hd)
     vg = jnp.moveaxis(cache.v_view(), 3, 1)
     return ref.paged_attention_ref(q.reshape(B, KV, G, hd), kg, vg,
-                                   cache.pos_view(), cur).reshape(B, H, hd)
+                                   cache.pos_view(), cur,
+                                   window=window).reshape(B, H, hd)
 
 
 @pytest.mark.parametrize("policy", POLICIES)

@@ -27,8 +27,18 @@ from repro.kernels.flash_prefill import (
     paged_flash_prefill_kernel,
     paged_flash_prefill_kernel_per_qhead,
 )
+from repro.kernels.paged_attention import (
+    BLOCK_TOKENS,
+    decode_block_pages,
+    paged_attention_kernel,
+    paged_attention_kernel_int8,
+)
 
-from tests.test_block_table_kernel import _dense_reference, _driven_cache
+from tests.test_block_table_kernel import (
+    _dense_reference,
+    _driven_cache,
+    _holed_cache,
+)
 from tests.test_prefix_sharing import _adopt, _filled_cache
 
 SPLITS = [1, 2, 4, 8]
@@ -42,19 +52,36 @@ GQA_CONFIGS = [("mixtral-8x7b", 1, 4), ("gemma3-27b", 2, 2)]
 # split-K flash-decode
 # ---------------------------------------------------------------------------
 
+def _scene(scene, policy, dtype, seed=0):
+    """A churned pool: a decode trace (``driven``) or the 73-slot table with
+    eviction holes and a wholly unmapped block (``holed``)."""
+    if scene == "holed":
+        return _holed_cache(dtype, seed=seed)
+    return _driven_cache(policy, 8, dtype, seed=seed)
+
+
+# (splits, scene, window): the driven cases keep their ids
+DECODE_CASES = [pytest.param(s, "driven", 0, id=str(s)) for s in SPLITS] + [
+    pytest.param(s, "holed", w, id=f"{s}-holed" + ("-window" if w else ""))
+    for w in (0, 40) for s in (1, 4)]
+
+
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
-@pytest.mark.parametrize("splits", SPLITS)
-def test_splitk_decode_matches_dense_ref(splits, dtype):
+@pytest.mark.parametrize("splits,scene,window", DECODE_CASES)
+def test_splitk_decode_matches_dense_ref(splits, scene, window, dtype):
     """Every split count reproduces the dense oracle on a churned pool
-    (freed + reallocated pages behind the block table)."""
-    cache, steps = _driven_cache("paged_eviction", 8, dtype)
+    (freed + reallocated pages behind the block table; for ``holed``, a
+    table that is not a whole number of decode blocks, holes inside a
+    block and a block with no page mapped), with and without a window."""
+    cache, steps = _scene(scene, "paged_eviction", dtype)
     B, KV, hd, G = 2, 2, 64, 2
     q = jax.random.normal(jax.random.PRNGKey(11), (B, KV * G, hd))
     cur = jnp.full((B,), steps - 1, jnp.int32)
     out = np.asarray(
-        ops.paged_attention(q, cache, cur_pos=cur, num_splits=splits),
-        np.float32)
-    exp = np.asarray(_dense_reference(q, cache, cur), np.float32)
+        ops.paged_attention(q, cache, cur_pos=cur, window=window,
+                            num_splits=splits), np.float32)
+    exp = np.asarray(_dense_reference(q, cache, cur, window=window),
+                     np.float32)
     tol = 1e-4 if dtype == "float32" else 5e-4
     np.testing.assert_allclose(out, exp, atol=tol, rtol=tol)
 
@@ -88,6 +115,39 @@ def test_splitk_decode_windowed():
             base = out
         else:
             np.testing.assert_allclose(out, base, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("P", [1, 7, 60, 73])
+def test_decode_block_pages_from_shapes(P):
+    """Pages per decode block: at least one, lane-aligned (a block's tokens
+    fill whole 128-lane rows), padding P by less than one block, and the
+    double-buffered K/V block within its VMEM cap."""
+    for page, hd, dtype in ((16, 128, jnp.bfloat16), (8, 64, jnp.float32),
+                            (32, 128, jnp.int8), (16, 256, jnp.float32)):
+        ppb = decode_block_pages(P, page, hd, dtype)
+        assert ppb >= 1 and (ppb * page) % 128 == 0
+        assert -(-P // ppb) * ppb - P < ppb
+        assert 4 * ppb * page * max(hd, 128) * jnp.dtype(dtype).itemsize \
+            <= 4 << 20
+    # the served geometry: BLOCK_TOKENS per block once P allows it
+    assert decode_block_pages(73, 16, 128, jnp.bfloat16) * 16 == BLOCK_TOKENS
+
+
+def test_holed_scene_covers_block_edges():
+    """The ``holed`` scene holds what the blocked walk must get right at the
+    decode block size: P not a multiple of it, a hole inside a live block,
+    and a block with no page mapped before the row's last live block."""
+    cache, _ = _holed_cache("float32")
+    B, P = cache.block_table.shape
+    ppb = decode_block_pages(P, 16, 64, jnp.float32)
+    assert P % ppb
+    nblk = -(-P // ppb)
+    bt = np.full((B, nblk * ppb), -1)
+    bt[:, :P] = np.asarray(cache.block_table)
+    mapped = (bt >= 0).reshape(B, nblk, ppb)
+    live = mapped.any(-1)
+    assert (live & ~mapped.all(-1)).any()
+    assert (~live[0, :np.nonzero(live[0])[0][-1]]).any()
 
 
 # ---------------------------------------------------------------------------
@@ -158,9 +218,11 @@ def test_gfold_on_live_pool_matches_dense_ref(arch, KV, G):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
-@pytest.mark.parametrize("splits", [1, 4])
-def test_fused_decode_scores_match_block_score_oracle(splits, dtype):
-    cache, steps = _driven_cache("paged_eviction", 8, dtype, seed=4)
+@pytest.mark.parametrize("splits,scene", [
+    pytest.param(s, sc, id=str(s) + ("-holed" if sc == "holed" else ""))
+    for sc in ("driven", "holed") for s in (1, 4)])
+def test_fused_decode_scores_match_block_score_oracle(splits, scene, dtype):
+    cache, steps = _scene(scene, "paged_eviction", dtype, seed=4)
     q = jax.random.normal(jax.random.PRNGKey(31), (2, 4, 64))
     cur = jnp.full((2,), steps - 1, jnp.int32)
     plain = ops.paged_attention(q, cache, cur_pos=cur, num_splits=splits)
@@ -171,6 +233,27 @@ def test_fused_decode_scores_match_block_score_oracle(splits, dtype):
     oracle = ops.page_scores(cache)
     np.testing.assert_allclose(np.asarray(scores), np.asarray(oracle),
                                atol=1e-4, rtol=1e-4)
+    # slots the walk never fetched carry zero norms, not stale VMEM
+    unmapped = ~np.asarray(cache.mapped_mask())
+    for norms in _kernel_norms(q, cache, cur, splits):
+        assert (np.asarray(norms).transpose(0, 2, 1, 3)[unmapped] == 0).all()
+
+
+def _kernel_norms(q, cache, cur, splits):
+    """The decode kernel's own (kn, vn) epilogue outputs, (B, KV, P, page)."""
+    B, H, hd = q.shape
+    KV = cache.k.shape[2]
+    args = (q.reshape(B, KV, H // KV, hd), ops._pool_layout(cache.k),
+            ops._pool_layout(cache.v))
+    if cache.quantized:
+        kernel = paged_attention_kernel_int8
+        args += (jnp.moveaxis(cache.k_scale, 2, 0),
+                 jnp.moveaxis(cache.v_scale, 2, 0))
+    else:
+        kernel = paged_attention_kernel
+    _, norms = kernel(*args, cache.pos, cache.block_table, cur,
+                      num_splits=splits, return_scores=True)
+    return norms
 
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])

@@ -194,7 +194,8 @@ def test_devstats_identities_raw_ops():
 # ---------------------------------------------------------------------------
 
 def _make_engine(policy, *, max_batch=3, budget=32, page=8, chunk=16,
-                 new_tokens=6, prompt_max=48, obs=None, sharing=True):
+                 new_tokens=6, prompt_max=48, obs=None, sharing=True,
+                 use_pallas=None):
     cfg = ASSIGNED_ARCHS["qwen2.5-3b"].reduced()
     params = init_model(jax.random.PRNGKey(0), cfg)
     ccfg = CacheConfig(page_size=page, cache_budget=budget, policy=policy,
@@ -202,7 +203,8 @@ def _make_engine(policy, *, max_batch=3, budget=32, page=8, chunk=16,
     return cfg, Engine(cfg, params, cache_cfg=ccfg, max_batch=max_batch,
                        max_prompt_len=prompt_max, max_new_tokens=new_tokens,
                        sampling=SamplingParams(greedy=True), chunk_size=chunk,
-                       prefix_sharing=sharing, obs=obs)
+                       prefix_sharing=sharing, obs=obs,
+                       use_pallas=use_pallas)
 
 
 def _host_pool_state(eng):
@@ -347,6 +349,43 @@ def test_disabled_obs_restores_bare_pytree():
     a = [r.output_tokens for r in off.scheduler.finished]
     b = [r.output_tokens for r in on.scheduler.finished]
     assert a == b, "telemetry changed sampled tokens"
+
+
+def _pallas_grids(jaxpr):
+    """Grid of every pallas_call in a jaxpr, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield tuple(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_grids(sub)
+
+
+def test_engine_gauges_the_decode_walk():
+    """With the Pallas kernels on, the engine records the decode walk its
+    wrapper runs: pages per block from the cache's shapes, and grid steps
+    per layer equal to the grid of the kernel that ``ops.paged_attention``
+    traces over the engine's own cache at ``max_batch`` rows."""
+    from repro.kernels import ops
+    from repro.kernels.paged_attention import decode_block_pages
+
+    cfg, eng = _make_engine("paged_eviction", use_pallas=True)
+    snap = eng.metrics_snapshot()
+    kv = jax.tree.map(lambda a: a[0], eng.cache.pattern[0].kv)
+    B, P = kv.block_table.shape
+    _, page, n_kv, hd = kv.k.shape
+    q = jnp.zeros((B, cfg.num_heads, hd), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, c: ops.paged_attention(
+        q, c, cur_pos=jnp.zeros((B,), jnp.int32), return_scores=True))(q, kv)
+    (grid,) = _pallas_grids(jaxpr.jaxpr)
+    assert B == eng.max_batch and grid[:2] == (B, n_kv)
+    ppb = snap["kernel.decode.pages_per_block"]["value"]
+    assert ppb == decode_block_pages(P, page, hd, kv.k.dtype)
+    assert grid[2] * grid[3] == -(-P // ppb)
+    assert snap["kernel.decode.grid_steps_per_layer"]["value"] == \
+        math.prod(grid)
+    # without the kernels there is no walk to record
+    _, plain = _make_engine("paged_eviction", use_pallas=False)
+    assert "kernel.decode.pages_per_block" not in plain.metrics_snapshot()
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,8 @@
 """Compile-only tests: the serving path's Pallas kernels, built by the Mosaic
 compiler for a described TPU v5e (no chip attached), in bf16 at the head
 geometries of Llama-3.2-3B (G=3, hd=128) and Llama-3.2-1B (G=4, hd=64),
-8 KV heads, page 16.
+8 KV heads, page 16, and the decode walk at the served Qwen2.5-3B geometry
+(G=8, hd=128, 2 KV heads, a 73-slot block table).
 
 Interpret mode checks none of Mosaic's rules (block tiling, layouts, VMEM),
 so these are the only tests that see what the chip's compiler would
@@ -25,9 +26,12 @@ from repro.kernels.paged_attention import (
     paged_attention_kernel_int8,
 )
 
-GEOMETRY = {"llama-3.2-3b": (3, 128), "llama-3.2-1b": (4, 64)}  # (G, hd)
+GEOMETRY = {"llama-3.2-3b": (3, 128), "llama-3.2-1b": (4, 64),
+            "qwen2.5-3b": (8, 128)}                          # (G, hd)
 KV, PAGE, B, SLOTS, T = 8, 16, 8, 65, 64
 N = B * SLOTS                                  # pool pages
+# the served decode-evict cell: budget 1024 + chunk 128 at page 16
+SERVED_KV, SERVED_SLOTS = 2, 73
 
 
 @pytest.fixture(scope="module")
@@ -47,13 +51,14 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _decode(sds, G, hd, *, splits, scores):
+def _decode(sds, G, hd, *, splits, scores, kv=KV, slots=SLOTS):
     fn = lambda *a: paged_attention_kernel(
         *a, interpret=False, num_splits=splits, return_scores=scores)
-    return fn, (sds((B, KV, G, hd), jnp.bfloat16),
-                sds((KV, N, PAGE, hd), jnp.bfloat16),
-                sds((KV, N, PAGE, hd), jnp.bfloat16),
-                sds((N, PAGE), jnp.int32), sds((B, SLOTS), jnp.int32),
+    n = B * slots
+    return fn, (sds((B, kv, G, hd), jnp.bfloat16),
+                sds((kv, n, PAGE, hd), jnp.bfloat16),
+                sds((kv, n, PAGE, hd), jnp.bfloat16),
+                sds((n, PAGE), jnp.int32), sds((B, slots), jnp.int32),
                 sds((B,), jnp.int32))
 
 
@@ -94,6 +99,8 @@ CASES = {
                                                     scores=True),
     "decode-split4": lambda s, g, h: _decode(s, g, h, splits=4, scores=False),
     "decode-int8-split4-scores": _decode_int8,
+    "decode-served-split1-scores": lambda s, g, h: _decode(
+        s, g, h, splits=1, scores=True, kv=SERVED_KV, slots=SERVED_SLOTS),
     "prefill-T64-scores": _prefill,
     "block-score": _block_score,
 }
@@ -105,6 +112,7 @@ CASES = {
     ("decode-split1-scores", "llama-3.2-1b"),
     ("decode-split4", "llama-3.2-1b"),
     ("decode-int8-split4-scores", "llama-3.2-3b"),
+    ("decode-served-split1-scores", "qwen2.5-3b"),
     ("prefill-T64-scores", "llama-3.2-3b"),
     ("prefill-T64-scores", "llama-3.2-1b"),
     ("block-score", "llama-3.2-3b"),
@@ -118,31 +126,37 @@ def test_kernel_compiles_for_v5e(one_chip, case, model):
 
 def test_tp4_decode_kernel_compiles_under_shard_map(topo):
     """Tensor-parallel serving runs the compiled decode kernel per shard
-    inside shard_map over a (1, 4) mesh, KV heads split across chips, and
-    reduces the fused-score norms across the mesh."""
+    inside shard_map over a (1, 4) mesh, KV heads split across chips (2 per
+    chip), and reduces the fused-score norms across the mesh: the blocked
+    walk's page copies and buffers at the local shapes, single-chain and
+    split, at Llama-3.2-3B's heads and at the served Qwen2.5-3B query group
+    over a 73-slot table."""
     from repro.models.moe import _shard_map
 
-    G, hd = GEOMETRY["llama-3.2-3b"]
     mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
-
-    def local(q, kp, vp, pos, bt, cur):
-        out, (kn, vn) = paged_attention_kernel(
-            q, kp, vp, pos, bt, cur, interpret=False, num_splits=4,
-            return_scores=True)
-        return out, jax.lax.pmean(kn.mean(1), "model")
-
     heads, pool, rep = P(None, "model"), P("model"), P()
-    fn = _shard_map(local, mesh,
-                    in_specs=(heads, pool, pool, rep, rep, rep),
-                    out_specs=(heads, rep), manual_axes=("data", "model"))
     sds = lambda shape, dt, spec: jax.ShapeDtypeStruct(
         shape, dt, sharding=NamedSharding(mesh, spec))
-    compiled = jax.jit(fn).lower(
-        sds((B, KV, G, hd), jnp.bfloat16, heads),
-        sds((KV, N, PAGE, hd), jnp.bfloat16, pool),
-        sds((KV, N, PAGE, hd), jnp.bfloat16, pool),
-        sds((N, PAGE), jnp.int32, rep), sds((B, SLOTS), jnp.int32, rep),
-        sds((B,), jnp.int32, rep)).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert "all-reduce" in text
+    for model, slots, splits in (("llama-3.2-3b", SLOTS, 4),
+                                 ("qwen2.5-3b", SERVED_SLOTS, 1)):
+        G, hd = GEOMETRY[model]
+        n = B * slots
+
+        def local(q, kp, vp, pos, bt, cur):
+            out, (kn, vn) = paged_attention_kernel(
+                q, kp, vp, pos, bt, cur, interpret=False,
+                num_splits=splits, return_scores=True)
+            return out, jax.lax.pmean(kn.mean(1), "model")
+
+        fn = _shard_map(local, mesh,
+                        in_specs=(heads, pool, pool, rep, rep, rep),
+                        out_specs=(heads, rep), manual_axes=("data", "model"))
+        compiled = jax.jit(fn).lower(
+            sds((B, KV, G, hd), jnp.bfloat16, heads),
+            sds((KV, n, PAGE, hd), jnp.bfloat16, pool),
+            sds((KV, n, PAGE, hd), jnp.bfloat16, pool),
+            sds((n, PAGE), jnp.int32, rep), sds((B, slots), jnp.int32, rep),
+            sds((B,), jnp.int32, rep)).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text, model
+        assert "all-reduce" in text, model
